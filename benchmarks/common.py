@@ -20,6 +20,9 @@ def run_sub(script: str, *args: str, devices: int = 1,
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # virtual CPU workers by design: never reach for a chip the parent
+    # process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = f"{SRC}:{env.get('PYTHONPATH', '')}"
     proc = subprocess.run(
         [sys.executable, str(REPO / "benchmarks" / script), *args],

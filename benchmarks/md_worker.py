@@ -27,6 +27,7 @@ import jax
 
 from repro.core.halo_plan import HaloSpec
 from repro.core.md import MDEngine, force_backends, make_grappa_like
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_md_mesh
 from repro.obs import MetricsRegistry, span, time_fn
 
@@ -71,6 +72,7 @@ def main():
                     help="write the run's metrics-registry records here "
                          "(input of `python -m repro.obs`)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     system = make_grappa_like(args.n_atoms, seed=1)
     mesh = make_md_mesh()

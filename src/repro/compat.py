@@ -1,53 +1,13 @@
-"""Compatibility shims across jax versions (0.4.x .. 0.6.x).
+"""Small helpers over the installed JAX (0.9) that several modules share.
 
-The repo targets the modern public API (``jax.shard_map``,
-``jax.sharding.AxisType``, ``jax.set_mesh``); older runtimes only ship the
-experimental spellings.  Import the symbols from here so every module works
-on both.
+``shard_map_norep`` is ``jax.shard_map`` with the replication check off,
+and ``named_axes_in_scope`` reads the mesh axes bound by enclosing
+shard_maps while tracing.
 """
 from __future__ import annotations
 
-import contextlib
-import inspect
-
 import jax
-
-try:  # jax >= 0.5
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SM_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, **kwargs):
-    """``shard_map`` accepting both kwarg spellings of the replication
-    check (``check_rep`` in jax<=0.5, ``check_vma`` later)."""
-    if "check_vma" in kwargs and "check_vma" not in _SM_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    if "check_rep" in kwargs and "check_rep" not in _SM_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(f, **kwargs)
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a dict across jax versions (older
-    jaxlibs return a one-element list of dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-
-    def mesh_axis_types(n: int):
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # jax 0.4.x: meshes are Auto-typed implicitly
-    AxisType = None
-
-    def mesh_axis_types(n: int):
-        return {}
+from jax._src import core as _core
 
 
 def shard_map_norep(f, *, mesh, in_specs, out_specs):
@@ -56,60 +16,17 @@ def shard_map_norep(f, *, mesh, in_specs, out_specs):
     Pallas calls have no replication rule, so bodies that may invoke them
     (the halo-plan backends) disable the check.
     """
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
-def set_mesh(mesh):
-    """``jax.set_mesh`` where available, else the Mesh context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return contextlib.nullcontext(mesh) if mesh is None else mesh
-
-
-def ensure_barrier_batching() -> bool:
-    """Register a vmap batching rule for ``lax.optimization_barrier``.
-
-    jax 0.4.x ships no batching rule for the barrier primitive, which
-    blocks ``vmap`` over any barrier-pinned program — including every MD
-    block body (the SimServer stacks replicas exactly that way).  The
-    barrier is semantically an elementwise identity, so the rule is the
-    identity on batch dims: bind the batched operands, pass the dims
-    through.  Idempotent; returns False when the private primitive
-    handle is unreadable (callers then know vmap-of-blocks is
-    unavailable on this jax).
-    """
-    try:
-        from jax._src.interpreters import batching
-        from jax._src.lax import lax as _lax_internal
-        prim = _lax_internal.optimization_barrier_p
-    except (ImportError, AttributeError):  # pragma: no cover - jax drift
-        return False
-    if prim in batching.primitive_batchers:
-        return True
-
-    def _rule(args, dims, **params):
-        return prim.bind(*args, **params), dims
-
-    batching.primitive_batchers[prim] = _rule
-    return True
-
-
-def named_axes_in_scope():
+def named_axes_in_scope() -> tuple:
     """Mesh axis names bound by enclosing shard_maps at trace time.
 
     Used by the ``"signal"`` halo backend: the Pallas *interpret-mode*
     remote-DMA emulation only supports a single named axis in scope
-    (``dma_start_p`` discharge), so multi-axis callers fall back to the
-    ppermute oracle on CPU.  Best-effort across jax versions — returns
-    ``None`` when the axis env is unreadable (callers should then assume
-    the conservative multi-axis case).
+    (``dma_start_p`` discharge), so multi-axis callers run the ppermute
+    oracle on CPU.
     """
-    try:
-        from jax._src import core as _core
-        env = _core.get_axis_env()
-        return tuple(n for n in env.axis_sizes if n is not None)
-    except (ImportError, AttributeError, TypeError):
-        # private-API probe: any jax version drift lands here, and the
-        # documented contract is "None = assume multi-axis"
-        return None
+    return tuple(n for n in _core.get_axis_env().axis_sizes
+                 if n is not None)

@@ -28,15 +28,15 @@ for the JAX reproduction:
 
 Backends are a registry; ``"serialized"`` and ``"fused"`` wrap the staged
 implementations in :mod:`repro.core.halo`, ``"pallas"`` drives the
-pack/put kernels of :mod:`repro.kernels.halo_pack` (interpret mode on CPU,
-with a pure-jnp oracle fallback).  New backends (double-buffered,
-multi-step, NVSHMEM-alike) plug in via :func:`register_backend`.
+pack/put kernels of :mod:`repro.kernels.halo_pack` (compiled on a TPU,
+interpreted on the CPU; a kernel failure raises).  New backends
+(double-buffered, multi-step, NVSHMEM-alike) plug in via
+:func:`register_backend`.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-import warnings
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -95,7 +95,6 @@ class HaloSpec:
     wrap_shift: Optional[Tuple[Tuple[float, ...], ...]] = None
     dtype: str = "float32"
     feature_elems: int = 1
-    interpret: bool = True   # pallas backend: interpreter mode (CPU/tests)
     pulses: Optional[Tuple[int, ...]] = None
     wire_dtype: Optional[str] = None
 
@@ -191,20 +190,6 @@ class FusedBackend(HaloBackend):
                                         self._local_shape(plan, ext))
 
 
-def _latch_halo_fallback(plan, e: Exception, context: str) -> None:
-    """Downgrade this plan to its jnp/ppermute oracle and warn once.
-
-    Trace-time kernel failures are backend-specific and expected (the
-    documented CPU fallback); the latch makes the downgrade loud exactly
-    once per plan instead of silently eating the error every pulse."""
-    if not plan._pallas_broken:
-        warnings.warn(
-            f"Pallas halo kernel {context} ({type(e).__name__}: {e}); "
-            "this halo plan falls back to its jnp/ppermute oracle for "
-            "the rest of this process", RuntimeWarning, stacklevel=3)
-    plan._pallas_broken = True
-
-
 class PallasBackend(HaloBackend):
     """Pack/unpack through the Pallas kernels of ``kernels.halo_pack``.
 
@@ -212,16 +197,14 @@ class PallasBackend(HaloBackend):
     send buffer, paper Alg. 3 line 7) -> ``ppermute`` (the put) ->
     concat / scatter-add (the unpack).  Index maps are static per local
     shape and cached on the plan — the analogue of the paper's DD-time
-    index-map build.  Falls back to pure-jnp oracles when the Pallas
-    kernels are unavailable on the current backend.  Pulses execute in
-    serialized (forwarding-chained) order, so the serialized
-    critical-path model applies.
+    index-map build.  Pulses execute in serialized (forwarding-chained)
+    order, so the serialized critical-path model applies.
     """
 
     name = "pallas"
     critical_path = "serialized"
 
-    # -- kernel dispatch with oracle fallback ------------------------------
+    # -- kernel dispatch ---------------------------------------------------
 
     def _pack(self, plan, src2d: jnp.ndarray, idx: np.ndarray,
               wire: Optional[str] = None) -> jnp.ndarray:
@@ -229,29 +212,13 @@ class PallasBackend(HaloBackend):
         wire dtype inside the kernel (fused quantize-into-pack: the wire
         format never materializes in HBM — only the packed send buffer
         and the received rows are wire-dtyped)."""
-        jidx = jnp.asarray(idx)
-        if not plan._pallas_broken:
-            try:
-                from repro.kernels import halo_pack
-                return halo_pack.pack(src2d, jidx,
-                                      interpret=plan.spec.interpret,
-                                      wire_dtype=wire)
-            except Exception as e:  # pragma: no cover - backend-specific
-                _latch_halo_fallback(plan, e, "pack failed")
-        rows = jnp.take(src2d, jidx, axis=0)
-        return rows if wire is None else rows.astype(jnp.dtype(wire))
+        from repro.kernels import halo_pack
+        return halo_pack.pack(src2d, jnp.asarray(idx), wire_dtype=wire)
 
     def _unpack_add(self, plan, dst2d: jnp.ndarray, idx: np.ndarray,
                     rows: jnp.ndarray) -> jnp.ndarray:
-        jidx = jnp.asarray(idx)
-        if not plan._pallas_broken:
-            try:
-                from repro.kernels import halo_pack
-                return halo_pack.unpack_add(dst2d, jidx, rows,
-                                            interpret=plan.spec.interpret)
-            except Exception as e:  # pragma: no cover - backend-specific
-                _latch_halo_fallback(plan, e, "unpack_add failed")
-        return dst2d.at[jidx].add(rows, mode="drop")
+        from repro.kernels import halo_pack
+        return halo_pack.unpack_add(dst2d, jnp.asarray(idx), rows)
 
     # -- static index maps (built once per local shape, cached) ------------
 
@@ -574,7 +541,6 @@ class HaloPlan:
         self._wrap = spec.wrap_shift_array()
         self._index_maps: Dict[Tuple[int, ...], Any] = {}
         self._stats_cache: Dict[Tuple, dict] = {}
-        self._pallas_broken = False
         self._exchange = self._make_exchange()
 
     # -- construction ------------------------------------------------------

@@ -56,7 +56,6 @@ from repro.core.md.pair_schedule import (
     force_backends,
     get_force_backend,
     inner_radius as default_inner_radius,
-    probe_pallas,
     prune_local,
     prune_radius,
     roll_prune,
@@ -272,11 +271,6 @@ class MDEngine:
         if force_backend != "dense":
             self.pair_schedule = PairSchedule.build(self.layout)
             self._pair_stats = self.pair_schedule.slot_pair_stats()
-            if force_backend == "pallas":
-                # compile-time kernel failures latch the jnp fallback
-                # here, before any block program is built (see
-                # pair_schedule.probe_pallas)
-                probe_pallas(system.params.ff, interpret=spec.interpret)
         else:
             # dense never builds a worklist (degenerate one-global-cell
             # layouts stay supported); mirror its accounting directly
@@ -377,17 +371,12 @@ class MDEngine:
 
         Per domain per step; ``prune_ratio`` is the dense-over-evaluated
         work reduction (1.0 for the dense backend).
-        ``pallas_fallback`` flags a ``"pallas"`` engine whose kernel
-        failed and is actually running the jnp twin.
         """
         out = dict(self._pair_stats)
         if self.nstprune:
             # live counter, not the last _bucket_exec's snapshot: a
             # final block's overflow has no further rebin to record it
             out["inner_overflow_blocks"] = self._inner_overflows
-        if self.force_backend == "pallas":
-            from repro.core.md.pair_schedule import pallas_fallback_active
-            out["pallas_fallback"] = pallas_fallback_active()
         self.obs.emit("pair_stats", data=out)
         self.obs.gauge("md/prune_ratio").set(out.get("prune_ratio", 1.0))
         return out
@@ -442,7 +431,7 @@ class MDEngine:
             self._trim_ext(ext_f), self._trim_ext(ext_i), self.layout,
             self.system.params.ff, sched=self.pair_schedule,
             sel=lax.slice(sel.reshape(-1), (0,), (tier_rows(tiers),)),
-            tiers=tiers, interpret=self.spec.interpret)
+            tiers=tiers)
         f_local = self.plan.rev_local(self._pad_force(F_trim, ext_f.shape))
         return f_local, lax.psum(pe, AXES)
 
@@ -464,14 +453,14 @@ class MDEngine:
         mass, dt = params.mass, params.dt
         layout, ff = self.layout, params.ff
         backend_fn = get_force_backend(self.force_backend)
-        sched, interp = self.pair_schedule, self.spec.interpret
+        sched = self.pair_schedule
 
         def eval_forces(ext_f_trim, ext_i_trim, ctx):
             if "pair_sel" not in ctx:      # dense: the unchanged path
                 return compute_forces(ext_f_trim, ext_i_trim, layout, ff)
             return backend_fn(ext_f_trim, ext_i_trim, layout, ff,
                               sched=sched, sel=ctx["pair_sel"],
-                              tiers=ctx["tiers"], interpret=interp)
+                              tiers=ctx["tiers"])
 
         def begin(cell_f, force, ctx):
             valid = ctx["cell_i"][..., 0] >= 0

@@ -146,24 +146,16 @@ def compute_forces(ext_f, ext_i, layout: CellLayout, ff: ForceField):
 # O(N^2) minimum-image oracle (tests only)
 # --------------------------------------------------------------------------
 
-def direct_forces_reference(pos, charge, typ, box, ff: ForceField):
-    """Direct-sum reference with minimum image; float64 numpy."""
-    pos = np.asarray(pos, np.float64)
-    q = np.asarray(charge, np.float64)
-    t = np.asarray(typ, np.int64)
-    box = np.asarray(box, np.float64)
-    n = pos.shape[0]
+def _direct_terms(dx, q_i, q_j, t_i, t_j, ff: ForceField, mask):
+    """Float64 pair force factor and energy of minimum-image ``dx``."""
     eps_t = np.asarray(ff.eps, np.float64)
     sig_t = np.asarray(ff.sigma, np.float64)
-
-    dx = pos[:, None, :] - pos[None, :, :]
-    dx -= box * np.round(dx / box)
     r2 = np.sum(dx * dx, axis=-1)
-    mask = (r2 < ff.r_cut ** 2) & ~np.eye(n, dtype=bool)
+    mask = mask & (r2 < ff.r_cut ** 2)
     r2safe = np.where(mask, r2, 1.0)
     inv_r2 = 1.0 / r2safe
-    eps = eps_t[t[:, None], t[None, :]]
-    sig = sig_t[t[:, None], t[None, :]]
+    eps = eps_t[t_i, t_j]
+    sig = sig_t[t_i, t_j]
     sr2 = sig * sig * inv_r2
     sr6 = sr2 ** 3
     sr12 = sr6 ** 2
@@ -171,10 +163,48 @@ def direct_forces_reference(pos, charge, typ, box, ff: ForceField):
     src6 = (sig * sig / ff.r_cut ** 2) ** 3
     e_lj = 4 * eps * ((sr12 - sr6) - (src6 ** 2 - src6))
     inv_r = np.sqrt(inv_r2)
-    qq = q[:, None] * q[None, :]
+    qq = q_i * q_j
     fac_c = qq * (inv_r * inv_r2 - 2 * ff.k_rf)
     e_c = qq * (inv_r + ff.k_rf * r2safe - ff.c_rf)
-    fac = np.where(mask, fac_lj + fac_c, 0.0)
-    pe = 0.5 * np.sum(np.where(mask, e_lj + e_c, 0.0))
+    return (np.where(mask, fac_lj + fac_c, 0.0),
+            np.where(mask, e_lj + e_c, 0.0))
+
+
+def direct_forces_reference(pos, charge, typ, box, ff: ForceField):
+    """Direct-sum reference with minimum image; float64 numpy."""
+    pos = np.asarray(pos, np.float64)
+    q = np.asarray(charge, np.float64)
+    t = np.asarray(typ, np.int64)
+    box = np.asarray(box, np.float64)
+    n = pos.shape[0]
+    dx = pos[:, None, :] - pos[None, :, :]
+    dx -= box * np.round(dx / box)
+    fac, e = _direct_terms(dx, q[:, None], q[None, :], t[:, None],
+                           t[None, :], ff, ~np.eye(n, dtype=bool))
     forces = np.sum(fac[..., None] * dx, axis=1)
-    return forces, pe
+    return forces, 0.5 * np.sum(e)
+
+
+def direct_forces_rows(pos, charge, typ, box, ff: ForceField, rows,
+                       chunk: int = 32):
+    """Float64 direct-sum forces on the atoms ``rows`` from ALL atoms.
+
+    The same minimum-image math as :func:`direct_forces_reference`,
+    evaluated ``chunk`` rows at a time so a subset of a large system can
+    be checked in O(chunk * N) memory.
+    """
+    pos = np.asarray(pos, np.float64)
+    q = np.asarray(charge, np.float64)
+    t = np.asarray(typ, np.int64)
+    box = np.asarray(box, np.float64)
+    rows = np.asarray(rows, np.int64)
+    out = np.zeros((rows.shape[0], 3))
+    for lo in range(0, rows.shape[0], chunk):
+        r = rows[lo:lo + chunk]
+        dx = pos[r, None, :] - pos[None, :, :]
+        dx -= box * np.round(dx / box)
+        fac, _ = _direct_terms(dx, q[r, None], q[None, :], t[r, None],
+                               t[None, :], ff,
+                               r[:, None] != np.arange(pos.shape[0]))
+        out[lo:lo + chunk] = np.sum(fac[..., None] * dx, axis=1)
+    return out

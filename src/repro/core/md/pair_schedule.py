@@ -57,8 +57,8 @@ This module is the pair-list analogue for the cell scheme:
     epilogues.
   - ``"pallas"`` — the same batches executed by the tuned Pallas
     cluster-pair kernel (:func:`repro.kernels.nonbonded.pair_forces_accum`,
-    interpret mode on CPU) with a jnp fallback if the kernel is
-    unavailable on the current backend.  Both sparse and pallas consume
+    compiled on a TPU, interpreted on the CPU; a kernel failure raises).
+    Both sparse and pallas consume
     the per-pair occupancy counts directly (validity masks are
     ``slot < count`` — binning packs each cell's atoms into a contiguous
     slot prefix).
@@ -444,6 +444,12 @@ def _pair_forces_jnp(a, b, ta, tb, same, cnt_a, cnt_b, ff: ForceField):
     tbi = jnp.clip(tb, 0, eps_t.shape[0] - 1)
     eps = eps_t[tai[:, :, None], tbi[:, None, :]]
     sig = sig_t[tai[:, :, None], tbi[:, None, :]]
+    # pin the pair-term operands too: left free, XLA fuses the r2 and
+    # gather producers into the pair-term fusion differently in the
+    # step pipeline's prologue than in its scan body, and the energy
+    # then rounds differently (1 ulp) between pipeline modes
+    dx, r2, q_a, q_b, eps, sig, mask = lax.optimization_barrier(
+        (dx, r2, q_a, q_b, eps, sig, mask))
     fac, pe = pair_terms(dx, r2, q_a[:, :, None], q_b[:, None, :],
                          eps, sig, ff, mask)
     fvec = lax.optimization_barrier(fac[..., None] * dx)
@@ -452,57 +458,9 @@ def _pair_forces_jnp(a, b, ta, tb, same, cnt_a, cnt_b, ff: ForceField):
     return fa, fb, lax.optimization_barrier(jnp.sum(pe, axis=(1, 2)))
 
 
-# pallas kernel availability is probed once and latched, mirroring
-# HaloPlan._pallas_broken (the jnp twin is the oracle fallback)
-_PALLAS_BROKEN = [False]
-
-
-def pallas_fallback_active() -> bool:
-    """True once the Pallas NB kernel has failed and the ``"pallas"``
-    backend is executing the jnp twin (surfaced via engine pair_stats)."""
-    return _PALLAS_BROKEN[0]
-
-
-def _latch_pallas_fallback(e: Exception, context: str) -> None:
-    """Latch the process-global jnp fallback and say so once, loudly."""
-    import warnings
-    _PALLAS_BROKEN[0] = True
-    warnings.warn(
-        f"Pallas NB kernel {context} ({type(e).__name__}: {e}); the "
-        "'pallas' force backend falls back to the jnp pair evaluator "
-        "for the rest of this process", RuntimeWarning, stacklevel=3)
-
-
-def probe_pallas(ff: ForceField, interpret: bool = True) -> bool:
-    """Eagerly compile+run the NB kernel on a tiny batch; latch fallback.
-
-    The try/except inside :func:`_eval_schedule` only sees *trace-time*
-    failures — on a real backend (``interpret=False``) Mosaic lowering
-    errors surface at jit-compile time, outside that guard.  Engines
-    selecting the ``"pallas"`` backend run this probe once at build time
-    so compile-time kernel failures also downgrade to the documented jnp
-    fallback instead of crashing the first block program.
-    """
-    if _PALLAS_BROKEN[0]:
-        return False
-    try:
-        from repro.kernels import nonbonded
-        z4 = jnp.zeros((8, 4, 4), jnp.float32)
-        t4 = jnp.full((8, 4), -1, jnp.int32)
-        c4 = jnp.zeros((8,), jnp.int32)
-        F, pe = nonbonded.pair_forces_accum(
-            z4, z4, t4, t4, c4, c4, c4, ff, 2, cnt_a=c4, cnt_b=c4,
-            interpret=interpret)
-        F.block_until_ready()
-        return True
-    except Exception as e:  # pragma: no cover - backend-specific
-        _latch_pallas_fallback(e, "failed its build-time probe")
-        return False
-
-
 def _eval_schedule(ext_f, ext_i, layout: CellLayout, ff: ForceField, *,
                    sched: PairSchedule, sel, tiers,
-                   use_pallas: bool, interpret: bool = True):
+                   use_pallas: bool):
     """Evaluate the tiered worklist: gather -> pair kernel -> scatter-add.
 
     ``tiers`` is the static ``((n_rows, k_slots), ...)`` ladder, deepest
@@ -523,19 +481,14 @@ def _eval_schedule(ext_f, ext_i, layout: CellLayout, ff: ForceField, *,
         off += int(n_t)
         a, b, ta, tb, same, ca, cb, cnt_a, cnt_b = _gather_tier(
             padded, sel_t, k_t)
-        F = pe_pairs = None
-        if use_pallas and not _PALLAS_BROKEN[0]:
-            try:
-                from repro.kernels import nonbonded
-                # the kernel + its scatter-accumulate epilogue; the
-                # sentinel row ne absorbs padding entries and is sliced
-                # off below
-                F, pe_pairs = nonbonded.pair_forces_accum(
-                    a, b, ta, tb, same, ca, cb, ff, ne + 1,
-                    cnt_a=cnt_a, cnt_b=cnt_b, interpret=interpret)
-            except Exception as e:  # pragma: no cover - backend-specific
-                _latch_pallas_fallback(e, "unavailable at trace time")
-        if F is None:
+        if use_pallas:
+            from repro.kernels import nonbonded
+            # the kernel + its scatter-accumulate epilogue; the sentinel
+            # row ne absorbs padding entries and is sliced off below
+            F, pe_pairs = nonbonded.pair_forces_accum(
+                a, b, ta, tb, same, ca, cb, ff, ne + 1,
+                cnt_a=cnt_a, cnt_b=cnt_b)
+        else:
             fa, fb, pe_pairs = _pair_forces_jnp(a, b, ta, tb, same,
                                                 cnt_a, cnt_b, ff)
             F = jnp.zeros((ne + 1, k_t, 3), ext_f.dtype)
@@ -568,17 +521,17 @@ def _norm_tiers(sel, tiers, k_exec):
 
 
 def _sparse(ext_f, ext_i, layout, ff, *, sched, sel, tiers=None,
-            k_exec=None, interpret=True):
+            k_exec=None):
     return _eval_schedule(ext_f, ext_i, layout, ff, sched=sched, sel=sel,
                           tiers=_norm_tiers(sel, tiers, k_exec),
-                          use_pallas=False, interpret=interpret)
+                          use_pallas=False)
 
 
 def _pallas(ext_f, ext_i, layout, ff, *, sched, sel, tiers=None,
-            k_exec=None, interpret=True):
+            k_exec=None):
     return _eval_schedule(ext_f, ext_i, layout, ff, sched=sched, sel=sel,
                           tiers=_norm_tiers(sel, tiers, k_exec),
-                          use_pallas=True, interpret=interpret)
+                          use_pallas=True)
 
 
 ForceBackend = Callable[..., Tuple[jnp.ndarray, jnp.ndarray]]

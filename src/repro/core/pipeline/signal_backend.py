@@ -15,11 +15,13 @@ production call-site):
   (put to the +1 neighbor) feeding ``unpack_add`` — Alg. 6's
   CommUnpackF.
 
-Kernels execute in interpreter mode on CPU (``HaloSpec.interpret``); when
-a kernel is unavailable on the current backend the plan degrades to a
-pure-jnp oracle with identical copy/accumulate semantics, so results stay
-bitwise-identical either way.  Index maps are static per local shape and
-cached on the plan, the analogue of the paper's DD-time index-map build.
+Kernels are compiled on a TPU and interpreted on the CPU
+(:func:`repro.kernels.interpret_mode`).  The interpreter emulates remote
+DMAs with only one named mesh axis in scope, so on the CPU a multi-axis
+call site runs a ppermute oracle with the kernels' exact copy semantics;
+that is the only route to the oracle, and on a TPU a kernel failure
+raises.  Index maps are static per local shape and cached on the plan,
+the analogue of the paper's DD-time index-map build.
 
 Like the other backends this one ships one hop per pulse, so halo widths
 must not exceed the local block (``w <= n``, the paper's single-pulse
@@ -37,8 +39,8 @@ from jax import lax
 
 from repro.compat import named_axes_in_scope
 from repro.core import halo as _halo
-from repro.core.halo_plan import (PallasBackend, _latch_halo_fallback,
-                                  register_backend)
+from repro.core.halo_plan import PallasBackend, register_backend
+from repro.kernels import interpret_mode
 
 
 class SignalBackend(PallasBackend):
@@ -49,7 +51,7 @@ class SignalBackend(PallasBackend):
     # the fused critical-path model describes this backend
     critical_path = "fused"
 
-    # -- transports with oracle fallback -----------------------------------
+    # -- transports ----------------------------------------------------------
 
     def _kernel_ok(self, plan) -> bool:
         """Can the remote-copy kernels run at this call site?
@@ -57,12 +59,9 @@ class SignalBackend(PallasBackend):
         Interpret mode (CPU validation) can only emulate remote DMAs with
         a single named axis in scope; real TPU lowering has no such limit.
         """
-        if plan._pallas_broken:
-            return False
-        if not plan.spec.interpret:
+        if not interpret_mode():
             return True
-        axes = named_axes_in_scope()
-        return axes is not None and len(axes) <= 1
+        return len(named_axes_in_scope()) <= 1
 
     def _put_rows(self, plan, src2d: jnp.ndarray, idx: np.ndarray, d: int,
                   shift: int, wire=None) -> jnp.ndarray:
@@ -77,14 +76,9 @@ class SignalBackend(PallasBackend):
         ring = plan.axis_sizes[d]
         jidx = jnp.asarray(idx)
         if self._kernel_ok(plan):
-            try:
-                from repro.kernels import halo_pack
-                return halo_pack.put_signal(src2d, jidx, axis=axis,
-                                            ring=ring, shift=shift,
-                                            interpret=plan.spec.interpret,
-                                            wire_dtype=wire)
-            except Exception as e:  # pragma: no cover - backend-specific
-                _latch_halo_fallback(plan, e, "put_signal failed")
+            from repro.kernels import halo_pack
+            return halo_pack.put_signal(src2d, jidx, axis=axis, ring=ring,
+                                        shift=shift, wire_dtype=wire)
         rows = jnp.take(src2d, jidx, axis=0)
         if wire is not None:
             rows = rows.astype(jnp.dtype(wire))
@@ -99,14 +93,10 @@ class SignalBackend(PallasBackend):
         ring = plan.axis_sizes[d]
         n_local = src2d.shape[0]
         if self._kernel_ok(plan):
-            try:
-                from repro.kernels import halo_pack
-                return halo_pack.fused_pulses(src2d, jnp.asarray(maps),
-                                              axis=axis, ring=ring,
-                                              n_local=n_local,
-                                              interpret=plan.spec.interpret)
-            except Exception as e:  # pragma: no cover - backend-specific
-                _latch_halo_fallback(plan, e, "fused_pulses failed")
+            from repro.kernels import halo_pack
+            return halo_pack.fused_pulses(src2d, jnp.asarray(maps),
+                                          axis=axis, ring=ring,
+                                          n_local=n_local)
         # jnp oracle with the kernel's exact semantics: entries >= n_local
         # read the previous pulse's receive buffer (staged forwarding),
         # padding entries produce zero rows, puts become ppermutes.

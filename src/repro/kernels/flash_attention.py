@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -74,7 +76,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
-                    bk: int = 256, interpret: bool = True):
+                    bk: int = 256, interpret: bool | None = None):
     """q: (BH, L, G, hd) grouped queries; k, v: (BH, S, hd).
 
     BH = batch * kv_heads (flattened); G = q heads per kv head.
@@ -108,5 +110,5 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
             pltpu.VMEM((bq, G), jnp.float32),
             pltpu.VMEM((bq, G, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

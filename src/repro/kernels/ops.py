@@ -1,7 +1,7 @@
 """Jitted public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on a
-real TPU deployment these flip to compiled mode unchanged.
+``interpret=None`` resolves through :func:`repro.kernels.interpret_mode`:
+the Pallas interpreter on the CPU, compiled kernels on a TPU.
 """
 from __future__ import annotations
 
@@ -17,19 +17,19 @@ from repro.kernels import nonbonded as _nb
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def pack(src, index_map, chunk: int = 128, interpret: bool = True):
+def pack(src, index_map, chunk: int = 128, interpret: bool | None = None):
     return _hp.pack(src, index_map, chunk=chunk, interpret=interpret)
 
 
 def put_signal(src, index_map, *, axis: str, ring: int, chunk: int = 128,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """Must be called inside shard_map over ``axis``."""
     return _hp.put_signal(src, index_map, axis, ring, chunk=chunk,
                           interpret=interpret)
 
 
 def fused_pulses(src, index_maps, *, axis: str, ring: int, n_local: int,
-                 chunk: int = 64, interpret: bool = True):
+                 chunk: int = 64, interpret: bool | None = None):
     """Fused dependency-partitioned multi-pulse exchange (shard_map)."""
     return _hp.fused_pulses(src, index_maps, axis, ring, n_local,
                             chunk=chunk, interpret=interpret)
@@ -37,7 +37,7 @@ def fused_pulses(src, index_maps, *, axis: str, ring: int, n_local: int,
 
 @functools.partial(jax.jit, static_argnames=("ff", "block", "interpret"))
 def pair_forces(a, b, ta, tb, same, ff: ForceField, block: int = 8,
-                interpret: bool = True):
+                interpret: bool | None = None):
     return _nb.pair_forces(a, b, ta, tb, same, ff, block=block,
                            interpret=interpret)
 
@@ -45,6 +45,6 @@ def pair_forces(a, b, ta, tb, same, ff: ForceField, block: int = 8,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, causal: bool = True, bq: int = 128,
-                    bk: int = 256, interpret: bool = True):
+                    bk: int = 256, interpret: bool | None = None):
     return _fa.flash_attention(q, k, v, causal=causal, bq=bq, bk=bk,
                                interpret=interpret)

@@ -30,7 +30,6 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from repro import compat
 from repro.obs import default_registry
 from repro.obs import span as obs_span
 from repro.configs import ARCH_IDS, SHAPES, get_config, shape_applicable
@@ -63,7 +62,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         if overrides and k in overrides:
             kw[k] = overrides[k]
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             prog = make_train_step(cfg, shape, ctx,
                                    microbatches=(overrides or {})
@@ -122,7 +121,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, overrides=None,
             "argument_size_in_bytes", "output_size_in_bytes",
             "temp_size_in_bytes", "alias_size_in_bytes",
             "generated_code_size_in_bytes")}
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis() or {}
         cost_d = {k: float(v) for k, v in cost.items()
                   if isinstance(v, (int, float)) and
                   k in ("flops", "bytes accessed")}

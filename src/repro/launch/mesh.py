@@ -15,9 +15,8 @@ from typing import Sequence
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from repro.compat import mesh_axis_types
 
 
 def make_mesh(shape: Sequence[int], axis_names: Sequence[str]) -> Mesh:
@@ -32,7 +31,8 @@ def make_mesh(shape: Sequence[int], axis_names: Sequence[str]) -> Mesh:
     if len(devs) < n:
         raise ValueError(f"need {n} devices, have {len(devs)}")
     arr = np.asarray(devs[:n]).reshape(tuple(shape))
-    return Mesh(arr, tuple(axis_names), **mesh_axis_types(len(axis_names)))
+    return Mesh(arr, tuple(axis_names),
+                axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -68,6 +68,8 @@ def main():
     ap.add_argument("--backend", default="dense",
                     choices=("dense", "sparse"))
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.md:
         return main_md(args)
     if args.arch is None:

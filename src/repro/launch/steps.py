@@ -19,7 +19,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig, ShapeCfg
 from repro.models.registry import build_model
 from repro.optim import adamw
@@ -248,7 +247,7 @@ def make_train_step(cfg: ArchConfig, shape: ShapeCfg, ctx: ShardingCtx,
                 g, ef = compressed_pod_mean(g, ef, pod_compress)
                 l = lax.pmean(l, "pod")
                 return g, ef, l, m
-            g, ef, l, m = shard_map(
+            g, ef, l, m = jax.shard_map(
                 pod_body, mesh=ctx.mesh,
                 in_specs=(P(), P("pod"), P()),
                 out_specs=(P(), P(), P(), P()),

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig, MoECfg
 from repro.models.layers import ParamDef, ParamDefs, mlp_defs, mlp_fwd
 from repro.parallel.sharding import ShardingCtx
@@ -255,7 +254,7 @@ def _moe_manual(p, x, cfg: ArchConfig, ctx: ShardingCtx, dispatch: str,
         return out.reshape(x_loc.shape), aux
 
     es = expert_specs(cfg, ctx)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(bspec), es["router"], es["w_gate"], es["w_up"],
                   es["w_down"]),

@@ -24,7 +24,6 @@ from __future__ import annotations
 import functools
 from typing import Optional, Tuple
 
-from repro.compat import shard_map
 
 import jax
 import jax.numpy as jnp
@@ -114,7 +113,7 @@ def ring_attention_sharded(q, k, v, mesh: Mesh, axis: str, *,
                            causal: bool = True, mode: str = "fused"):
     """shard_map wrapper: q/k/v (B, L, H, hd) sharded on L over ``axis``."""
     ring = mesh.shape[axis]
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis=axis, ring=ring,
                           causal=causal, mode=mode),
         mesh=mesh,

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import ensure_barrier_batching, shard_map_norep
+from repro.compat import shard_map_norep
 from repro.core.md.domain import AXES
 from repro.core.md.engine import MDEngine
 from repro.core.md.pair_schedule import SLOT_QUANTUM
@@ -145,10 +145,6 @@ class SimServer:
                  wave_timeout_s: Optional[float] = None,
                  watchdog: Optional[Watchdog] = None,
                  obs: Optional[MetricsRegistry] = None):
-        if not ensure_barrier_batching():
-            raise RuntimeError(
-                "this jax exposes no optimization_barrier batching hook; "
-                "vmapped MD blocks are unavailable")
         self.mesh = mesh if mesh is not None else make_mesh((1, 1, 1), AXES)
         names = tuple(self.mesh.axis_names)
         if names == AXES:

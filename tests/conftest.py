@@ -19,6 +19,9 @@ def run_dist_script(script: str, *args: str, devices: int = 8,
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # virtual CPU workers by design: never reach for a chip the parent
+    # process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = f"{SRC}:{env.get('PYTHONPATH', '')}"
     if extra_env:
         env.update(extra_env)

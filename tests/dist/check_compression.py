@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.optim.compression import compressed_pod_mean, ef_init
 
@@ -39,7 +38,7 @@ def main():
         return out["w"], ef["w"]
 
     for mode in (None, "int8", "topk"):
-        fn = shard_map(functools.partial(reduce_step, mode=mode),
+        fn = jax.shard_map(functools.partial(reduce_step, mode=mode),
                        mesh=mesh, in_specs=(P("pod"), P("pod")),
                        out_specs=(P("pod"), P("pod")), check_vma=False)
         gshard = gstack.reshape(8 * 64, 8)

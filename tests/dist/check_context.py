@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.parallel.context import (
     distributed_decode,
@@ -70,7 +69,7 @@ def main():
         return distributed_decode(q1, k_shard, v_shard, cache_len, "seq",
                                   off)
 
-    fn = shard_map(functools.partial(decode_local), mesh=mesh,
+    fn = jax.shard_map(functools.partial(decode_local), mesh=mesh,
                    in_specs=(P(), P(None, "seq"), P(None, "seq"), P()),
                    out_specs=P(), check_vma=False)
     got = np.asarray(fn(q1, k, v, cache_len))

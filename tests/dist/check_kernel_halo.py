@@ -117,16 +117,18 @@ def main():
         F, pe = nonbonded.pair_forces_accum(a, b, ta, tb, same, ca, cb,
                                             DEFAULT_FF, n_cells,
                                             epilogue="pallas")
-        return F, pe
+        # the per-pair forces the epilogue consumed, from the same
+        # program: interpret-mode rounding depends on the surrounding
+        # XLA program, so the oracle must accumulate these exact values
+        fa, fb, pe_ref = nonbonded.pair_forces(a, b, ta, tb, same,
+                                               DEFAULT_FF)
+        return F, pe, fa, fb, pe_ref
 
     fn = shard_map_norep(nb_body, mesh=mesh, in_specs=(P("z"),) * 7,
-                         out_specs=(P("z"), P("z")))
-    F_got, pe_got = jax.jit(fn)(*map(jnp.asarray,
-                                     (a, b, ta, tb, same, ca, cb)))
+                         out_specs=(P("z"),) * 5)
+    F_got, pe_got, fa, fb, pe_ref = jax.jit(fn)(
+        *map(jnp.asarray, (a, b, ta, tb, same, ca, cb)))
     F_got = np.asarray(F_got).reshape(RING, n_cells, k, 3)
-
-    fa, fb, pe_ref = nonbonded.pair_forces(
-        *map(jnp.asarray, (a, b, ta, tb, same)), DEFAULT_FF)
     fa, fb = np.asarray(fa), np.asarray(fb)
     F_ref = np.zeros((RING, n_cells, k, 3), np.float32)
     for i in range(RING * n_pair):
@@ -137,6 +139,40 @@ def main():
                           np.asarray(pe_ref)), "pair energies"
     print("pair_forces_accum: scatter epilogue bitwise == sequential "
           "oracle (4 device batches)")
+
+    # ---- compiled-path protocol across devices (TPU interpreter) ------
+    # mesh-coordinate peers over a (2,2,1) mesh + barrier handshake: the
+    # plain interpreter cannot run this (one named axis only)
+    from jax.experimental.pallas import tpu as pltpu
+    mesh3 = make_mesh((2, 2, 1), ("z", "y", "x"))
+    spec3 = P("z", "y", "x")
+    x3 = jnp.asarray(rng.randn(2 * 30, 2 * 5, 1, F).astype(np.float32))
+    idx3 = jnp.asarray(rng.randint(-1, 150, 200).astype(np.int32))
+    tpu_interp = pltpu.InterpretParams(detect_races=True)
+    for axis, ring in (("z", 2), ("y", 2), ("x", 1)):
+        for shift in (-1, 1):
+            perm = [(j, (j + shift) % ring) for j in range(ring)]
+
+            def put3(lo, axis=axis, ring=ring, shift=shift):
+                return halo_pack.put_signal(
+                    lo.reshape(150, F), idx3, axis=axis, ring=ring,
+                    shift=shift, chunk=64, interpret=tpu_interp
+                ).reshape(1, 1, 1, 200, F)
+
+            def oracle3(lo, axis=axis, perm=perm):
+                rows = jnp.take(lo.reshape(150, F), jnp.maximum(idx3, 0),
+                                axis=0)
+                rows = jnp.where((idx3 >= 0)[:, None], rows, 0.0)
+                return lax.ppermute(rows, axis, perm).reshape(1, 1, 1, 200,
+                                                              F)
+
+            got3, ref3 = (np.asarray(jax.jit(shard_map_norep(
+                f, mesh=mesh3, in_specs=(spec3,), out_specs=spec3))(x3))
+                for f in (put3, oracle3))
+            assert np.array_equal(got3, ref3), ("tpu-interp put", axis,
+                                                shift)
+    print("put_signal on a (2,2,1) mesh in the TPU interpreter: bitwise "
+          "== ppermute oracle on every axis and direction")
 
     print("check_kernel_halo OK")
 

@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import cost_analysis
 from repro.launch import hlo_analysis as H
 
 
@@ -18,7 +17,7 @@ def test_flops_match_cost_analysis_loop_free():
     args = [jax.ShapeDtypeStruct(s, jnp.float32)
             for s in [(64, 128), (128, 256), (256, 32)]]
     c = jax.jit(f).lower(*args).compile()
-    want = cost_analysis(c)["flops"]
+    want = c.cost_analysis()["flops"]
     got = H.analyze(c.as_text())["flops"]
     # the parser counts dots only; elementwise tanh adds a small delta
     assert abs(got - want) / want < 0.01, (got, want)

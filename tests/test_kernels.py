@@ -103,3 +103,96 @@ def test_flash_attention_bf16():
 def test_halo_put_and_fused_pulses(dist):
     out = dist("check_kernel_halo.py", devices=4)
     assert "check_kernel_halo OK" in out
+
+
+# ---- no silent fallback ---------------------------------------------------------
+
+def _refuse(*args, **kwargs):
+    raise RuntimeError("kernel refused")
+
+
+@pytest.mark.parametrize("backend,kernel", [("pallas", "pack"),
+                                            ("signal", "put_signal")])
+def test_halo_kernel_failure_raises(monkeypatch, backend, kernel):
+    """A failing halo kernel propagates: no latch downgrades the plan to
+    its jnp/ppermute oracle, on this call or any later one."""
+    from repro.core.halo_plan import HaloPlan, HaloSpec
+    from repro.kernels import halo_pack
+    from repro.launch.mesh import make_mesh
+
+    monkeypatch.setattr(halo_pack, kernel, _refuse)
+    plan = HaloPlan.build(HaloSpec(("x",), (1,), backend=backend),
+                          make_mesh((1,), ("x",)))
+    x = jnp.arange(12.0, dtype=jnp.float32).reshape(4, 3)
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            plan.fwd(x)
+
+
+def test_nb_kernel_failure_raises(monkeypatch):
+    """The pallas force backend has no jnp fallback either."""
+    from repro.core.md import MDEngine, make_grappa_like
+    from repro.kernels import nonbonded
+    from repro.launch.mesh import make_mesh
+
+    monkeypatch.setattr(nonbonded, "pair_forces_accum", _refuse)
+    eng = MDEngine(make_grappa_like(200, seed=5),
+                   make_mesh((1, 1, 1), ("z", "y", "x")),
+                   force_backend="pallas")
+    assert eng.force_backend == "pallas"
+    cf, ci = eng.init_state()
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            eng.force_fn(cf, ci)
+
+
+def test_interpret_mode_follows_the_platform():
+    from repro.kernels import interpret_mode
+    assert interpret_mode() is (jax.default_backend() == "cpu")
+    assert interpret_mode(False) is False
+    assert interpret_mode(True) is True
+
+
+@pytest.mark.parametrize("axis", ["z", "x"])
+def test_remote_kernels_mesh_path_in_tpu_interpreter(axis):
+    """The remote-copy kernels' compiled-path protocol — peers addressed
+    by mesh coordinates over all three MD axes, barrier handshake — run
+    by Pallas' TPU interpreter (DMA/semaphore semantics with race
+    detection), which the plain interpreter cannot emulate."""
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.sharding import PartitionSpec as P
+    from repro.compat import shard_map_norep
+    from repro.kernels import halo_pack
+    from repro.launch.mesh import make_mesh
+
+    tpu_interp = pltpu.InterpretParams(detect_races=True)
+    rng = np.random.RandomState(3)
+    n_local, F, M = 40, 5, 70
+    x = jnp.asarray(rng.randn(n_local, 1, 1, F).astype(np.float32))
+    idx = rng.randint(-1, n_local, M).astype(np.int32)
+    maps = np.full((2, M), -1, np.int32)
+    maps[0, :60] = rng.randint(0, n_local, 60)
+    maps[1, :50] = rng.randint(0, n_local + M, 50)
+
+    def body(lo):
+        lo = lo.reshape(n_local, F)
+        outs = [halo_pack.put_signal(lo, jnp.asarray(idx), axis=axis,
+                                     ring=1, shift=s, chunk=32,
+                                     interpret=tpu_interp)
+                for s in (-1, 1)]
+        fused = halo_pack.fused_pulses(lo, jnp.asarray(maps), axis=axis,
+                                       ring=1, n_local=n_local, chunk=32,
+                                       interpret=tpu_interp)
+        return jnp.concatenate(outs + [fused.reshape(2 * M, F)])[None, None]
+
+    spec = P("z", "y", "x")
+    got = np.asarray(jax.jit(shard_map_norep(
+        body, mesh=make_mesh((1, 1, 1), ("z", "y", "x")), in_specs=(spec,),
+        out_specs=spec))(x)).reshape(4 * M, F)
+    src = np.asarray(x).reshape(n_local, F)
+    put = ref.pack_ref(src, idx)
+    # ring of one: every put lands back here; pulse 2 forwards pulse 1
+    p1 = ref.pack_ref(src, maps[0])
+    p2 = np.concatenate([src, p1])[np.maximum(maps[1], 0)]
+    p2[maps[1] < 0] = 0.0
+    np.testing.assert_array_equal(got, np.concatenate([put, put, p1, p2]))

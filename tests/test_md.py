@@ -98,3 +98,15 @@ def test_dd_equivalence_and_migration(dist):
 def test_nve_float64(dist):
     out = dist("check_md_nve.py")
     assert "check_md_nve OK" in out
+
+
+def test_direct_forces_rows_matches_reference(small_system):
+    """The chunked subset oracle is the full oracle's rows."""
+    from repro.core.md import direct_forces_rows
+    s = small_system
+    f_ref, _ = direct_forces_reference(s.pos, s.charge, s.typ, s.box,
+                                       s.params.ff)
+    rows = np.random.RandomState(1).choice(s.n_atoms, 37, replace=False)
+    f_rows = direct_forces_rows(s.pos, s.charge, s.typ, s.box, s.params.ff,
+                                rows, chunk=8)
+    np.testing.assert_allclose(f_rows, f_ref[rows], rtol=1e-12, atol=1e-12)

@@ -195,8 +195,11 @@ def test_ledger_window_replay_properties(depth, n_steps, n_pulses):
     assert bool(led.drained(st_))                     # epilogue drains all
     assert int(led.in_flight(st_)) == 0
     s = led.summary(st_)
-    assert s["fwd"]["released"] == s["fwd"]["acquired"] == n_steps
-    assert s["rev"]["released"] == s["rev"]["acquired"] == n_steps
+    # summary totals sum over the per-pulse counters: every step releases
+    # and acquires each of its n_pulses pulses once
+    n_ops = n_steps * n_pulses
+    assert s["fwd"]["released"] == s["fwd"]["acquired"] == n_ops
+    assert s["rev"]["released"] == s["rev"]["acquired"] == n_ops
 
 
 @given(depth=st.integers(2, 4), extra=st.integers(1, 4))
