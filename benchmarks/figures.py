@@ -360,8 +360,8 @@ def pipeline_bench(smoke: bool = False, out: str = None):
     emit("pipeline/exposed_phases_monotone_in_depth", 0.0,
          str(exposed_monotone))
 
-    # traced sample: metrics JSONL -> Perfetto trace with measured +
-    # predicted lanes (CI uploads both as artifacts)
+    # traced sample: metrics JSONL -> Perfetto trace of the measured
+    # host spans and counters (CI uploads both as artifacts)
     obs_jsonl = RESULTS / "obs" / "pipeline_smoke.jsonl"
     trace_path = RESULTS / "trace_pipeline.json"
     try:

@@ -684,8 +684,8 @@ class HaloPlan:
         The registry stays out of the stats cache key: this is a separate
         method so ``stats`` callers keep their memoization while emitters
         (engine build, benchmarks) push the same dict — plus the backend's
-        critical-path model, which the Perfetto exporter's predicted lanes
-        key on — into a :class:`~repro.obs.registry.MetricsRegistry`.
+        critical-path model — into a
+        :class:`~repro.obs.registry.MetricsRegistry`.
         """
         stats = self.stats(local_shape, **kw)
         registry.emit("halo_stats", backend=self.spec.backend,

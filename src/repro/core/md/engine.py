@@ -621,24 +621,27 @@ class MDEngine:
             return block_sched_impl(cell_f, cell_i, force, sel, None,
                                     n_steps, tiers, tiers_inner)
 
-        def do_rebin(cell_f, cell_i):
-            new_f, new_i, diag = rebin(cell_f, cell_i, layout, mig_cap)
-            force, pe = self._force_pass(new_f[..., :4], new_i)
-            force = jnp.where(new_i[..., 0:1] >= 0, force, 0.0)
+        def rebin_program(cell_f, cell_i):
+            with sc("rebin"):
+                new_f, new_i, diag = rebin(cell_f, cell_i, layout, mig_cap)
+                with sc("rebin_force"):
+                    force, pe = self._force_pass(new_f[..., :4], new_i)
+                    force = jnp.where(new_i[..., 0:1] >= 0, force, 0.0)
             return new_f, new_i, force, diag
 
-        def do_prune(cell_f, cell_i):
-            ext_f = self.plan.fwd_local(cell_f[..., :4])
-            ext_i = self.plan.fwd_local(cell_i, wrap_shift=None)
-            sel, cum, cum_inner, occ = prune_local(
-                self.pair_schedule, self._trim_ext(ext_f),
-                self._trim_ext(ext_i), self.r_prune,
-                r_inner=self.r_inner)
-            # the exec shapes must agree across the SPMD mesh: every
-            # domain sizes to the global worst case
-            cum = lax.pmax(cum, AXES)
-            cum_inner = lax.pmax(cum_inner, AXES)
-            occ = lax.pmax(occ, AXES)
+        def prune_program(cell_f, cell_i):
+            with sc("prune"):
+                ext_f = self.plan.fwd_local(cell_f[..., :4])
+                ext_i = self.plan.fwd_local(cell_i, wrap_shift=None)
+                sel, cum, cum_inner, occ = prune_local(
+                    self.pair_schedule, self._trim_ext(ext_f),
+                    self._trim_ext(ext_i), self.r_prune,
+                    r_inner=self.r_inner)
+                # the exec shapes must agree across the SPMD mesh: every
+                # domain sizes to the global worst case
+                cum = lax.pmax(cum, AXES)
+                cum_inner = lax.pmax(cum_inner, AXES)
+                occ = lax.pmax(occ, AXES)
             return sel[None, None, None], cum, cum_inner, occ
 
         # device-local program bodies, exposed for external composition:
@@ -649,7 +652,7 @@ class MDEngine:
         # run of the same engine config)
         self.local_programs = {
             "block": block, "block_sched": block_sched,
-            "rebin": do_rebin, "prune": do_prune,
+            "rebin": rebin_program, "prune": prune_program,
         }
 
         # overlap_rebin: the nstlist-cadence DLB work (migration gather +
@@ -663,7 +666,7 @@ class MDEngine:
                                                      n_steps)
             cell_f, cell_i = lax.optimization_barrier((cell_f, cell_i))
             with sc("rebin_seam"):
-                new_f, new_i, force, diag = do_rebin(cell_f, cell_i)
+                new_f, new_i, force, diag = rebin_program(cell_f, cell_i)
             return new_f, new_i, force, metrics, diag
 
         def block_sched_rebin(cell_f, cell_i, force, sel, n_steps, tiers,
@@ -672,8 +675,8 @@ class MDEngine:
                 cell_f, cell_i, force, sel, n_steps, tiers, tiers_inner)
             cell_f, cell_i = lax.optimization_barrier((cell_f, cell_i))
             with sc("rebin_seam"):
-                new_f, new_i, force, diag = do_rebin(cell_f, cell_i)
-                sel2, cum, cum_inner, occ = do_prune(new_f, new_i)
+                new_f, new_i, force, diag = rebin_program(cell_f, cell_i)
+                sel2, cum, cum_inner, occ = prune_program(new_f, new_i)
             return (new_f, new_i, force, metrics, diag, sel2, cum,
                     cum_inner, occ, ovf)
 
@@ -700,7 +703,7 @@ class MDEngine:
                 static_argnums=(3,),
             )
         self.rebin_fn = jax.jit(shard_map_norep(
-            do_rebin, mesh=self.mesh, in_specs=(spec, spec),
+            rebin_program, mesh=self.mesh, in_specs=(spec, spec),
             out_specs=(spec, spec, spec, P())))
         self._force_fn_dense = jax.jit(shard_map_norep(
             lambda f, i: self._force_pass(f[..., :4], i),
@@ -735,7 +738,7 @@ class MDEngine:
                     static_argnums=(4, 5, 6),
                 )
             self.prune_fn = jax.jit(shard_map_norep(
-                do_prune, mesh=self.mesh, in_specs=(spec, spec),
+                prune_program, mesh=self.mesh, in_specs=(spec, spec),
                 out_specs=(spec, P(), P(), P())))
             self._force_fn_sched = jax.jit(
                 shard_map_norep(
@@ -824,7 +827,8 @@ class MDEngine:
         """
         if self.force_backend == "dense":
             return None
-        sel, cum, cum_inner, occ = self.prune_fn(cell_f, cell_i)
+        with obs_span("prune_dispatch", self.obs):
+            sel, cum, cum_inner, occ = self.prune_fn(cell_f, cell_i)
         return self._bucket_exec(sel, cum, cum_inner, occ,
                                  disable_inner=disable_inner)
 
@@ -835,56 +839,65 @@ class MDEngine:
         (shared by the host-dispatched and ``overlap_rebin``-fused
         prunes).  ``disable_inner`` is the overflow fallback — one block
         on the outer ladder after a refresh outgrew the inner one."""
-        M = self.pair_schedule.n_pairs
-        K = self.layout.capacity
-        cum = [int(v) for v in jax.device_get(cum)]
-        cum_inner = [int(v) for v in jax.device_get(cum_inner)]
-        occ = int(jax.device_get(occ))
-        n_keep = cum[0]                 # measured survivors (stats stay honest)
-        if self.static_ladder:
-            # worst-case histogram: all M rows at the deepest level — one
-            # (M, K) tier, constant across blocks and across replicas
-            cum = [M] * len(cum)
-        tiers = tier_plan(cum, self.pair_bucket, M, SLOT_QUANTUM, K)
-        tiers_inner = ()
-        if self.nstprune and not disable_inner:
-            # inner ladder: rebin-time inner histogram, safety-margined
-            # for drift until the next rebin, never above the outer one
-            cum_in = [min(int(math.ceil(ci * self.inner_safety)), co)
-                      for ci, co in zip(cum_inner, cum)]
-            tiers_inner = tier_plan(cum_in, self.pair_bucket, M,
-                                    SLOT_QUANTUM, K)
-        # what the old single-rectangle schedule (one global k_exec)
-        # would have evaluated — the PR's per-pair-bound gain baseline
-        global_kexec = bucket(cum[0], self.pair_bucket, M) * \
-            bucket(occ, SLOT_QUANTUM, K) ** 2 if cum[0] else 0
-        self._pair_stats = self.pair_schedule.slot_pair_stats(
-            tiers=tiers, tiers_inner=tiers_inner, n_keep=n_keep,
-            n_inner=cum_inner[0], max_occupancy=occ,
-            global_kexec_slot_pairs=global_kexec)
-        self._pair_stats.update({
-            "force_backend": self.force_backend,
-            "nstprune": self.nstprune,
-            "inner_radius": self.r_inner,
-            "inner_overflow_blocks": self._inner_overflows,
-            "inner_disabled": bool(self.nstprune and disable_inner),
-        })
-        outer_rows = tier_rows(tiers)
-        inner_rows = tier_rows(tiers_inner) if tiers_inner else outer_rows
-        self.sched_history.append((outer_rows, inner_rows))
-        self.obs.gauge("md/outer_rows").set(outer_rows)
-        self.obs.gauge("md/inner_rows").set(inner_rows)
-        self.obs.emit("sched_update", block=len(self.sched_history),
-                      outer_rows=outer_rows, inner_rows=inner_rows,
-                      max_occupancy=occ,
-                      inner_disabled=bool(self.nstprune and disable_inner))
-        self._sched_exec = (sel, tiers, tiers_inner)
-        return self._sched_exec
+        with obs_span("schedule_read", self.obs):
+            M = self.pair_schedule.n_pairs
+            K = self.layout.capacity
+            cum = [int(v) for v in jax.device_get(cum)]
+            cum_inner = [int(v) for v in jax.device_get(cum_inner)]
+            occ = int(jax.device_get(occ))
+            n_keep = cum[0]         # measured survivors (stats stay honest)
+            if self.static_ladder:
+                # worst-case histogram: all M rows at the deepest level —
+                # one (M, K) tier, constant across blocks and replicas
+                cum = [M] * len(cum)
+            tiers = tier_plan(cum, self.pair_bucket, M, SLOT_QUANTUM, K)
+            tiers_inner = ()
+            if self.nstprune and not disable_inner:
+                # inner ladder: rebin-time inner histogram, safety-
+                # margined for drift until the next rebin, never above
+                # the outer one
+                cum_in = [min(int(math.ceil(ci * self.inner_safety)), co)
+                          for ci, co in zip(cum_inner, cum)]
+                tiers_inner = tier_plan(cum_in, self.pair_bucket, M,
+                                        SLOT_QUANTUM, K)
+            # what the old single-rectangle schedule (one global
+            # k_exec) would have evaluated — the per-pair-bound gain
+            # baseline
+            global_kexec = bucket(cum[0], self.pair_bucket, M) * \
+                bucket(occ, SLOT_QUANTUM, K) ** 2 if cum[0] else 0
+            self._pair_stats = self.pair_schedule.slot_pair_stats(
+                tiers=tiers, tiers_inner=tiers_inner, n_keep=n_keep,
+                n_inner=cum_inner[0], max_occupancy=occ,
+                global_kexec_slot_pairs=global_kexec)
+            self._pair_stats.update({
+                "force_backend": self.force_backend,
+                "nstprune": self.nstprune,
+                "inner_radius": self.r_inner,
+                "inner_overflow_blocks": self._inner_overflows,
+                "inner_disabled": bool(self.nstprune and disable_inner),
+            })
+            outer_rows = tier_rows(tiers)
+            inner_rows = (tier_rows(tiers_inner) if tiers_inner
+                          else outer_rows)
+            self.sched_history.append((outer_rows, inner_rows))
+            self.obs.gauge("md/outer_rows").set(outer_rows)
+            self.obs.gauge("md/inner_rows").set(inner_rows)
+            self.obs.emit(
+                "sched_update", block=len(self.sched_history),
+                outer_rows=outer_rows, inner_rows=inner_rows,
+                max_occupancy=occ,
+                inner_disabled=bool(self.nstprune and disable_inner))
+            self._sched_exec = (sel, tiers, tiers_inner)
+            return self._sched_exec
 
     def _note_overflow(self, ovf) -> bool:
         """Record a block's rolling-prune overflow scalar; True if the
         next block must fall back to the outer ladder."""
-        if not self.nstprune or int(jax.device_get(ovf)) == 0:
+        if not self.nstprune:
+            return False
+        with obs_span("overflow_read", self.obs):
+            ovf = int(jax.device_get(ovf))
+        if ovf == 0:
             return False
         self._inner_overflows += 1
         self.obs.counter("md/inner_overflow_blocks").inc()
@@ -909,10 +922,12 @@ class MDEngine:
             cell_f, cell_i = state
         with obs_span("rebin_dispatch", self.obs):
             cell_f, cell_i, force, diag = self.rebin_fn(cell_f, cell_i)
-            sched = self._refresh_schedule(cell_f, cell_i,
-                                           disable_inner=disable_inner)
+        sched = self._refresh_schedule(cell_f, cell_i,
+                                       disable_inner=disable_inner)
+        with obs_span("diag_read", self.obs):
+            diag = jax.device_get(diag)
         return RunState(cell_f, cell_i, force, sched,
-                        bool(disable_inner), 0, [jax.device_get(diag)])
+                        bool(disable_inner), 0, [diag])
 
     def _fault_operand(self, fault_vec):
         """Normalize a fault vector to the replicated int32 operand the
@@ -987,7 +1002,8 @@ class MDEngine:
         self.obs.counter("md/steps").inc(take)
         rs.step += take
         if fuse:
-            rs.diags.append(jax.device_get(diag))
+            with obs_span("diag_read", self.obs):
+                rs.diags.append(jax.device_get(diag))
         return m
 
     def advance_schedule(self, rs: RunState):
@@ -997,12 +1013,13 @@ class MDEngine:
         with obs_span("rebin_dispatch", self.obs):
             cell_f, cell_i, force, diag = self.rebin_fn(rs.cell_f,
                                                         rs.cell_i)
-            rs.sched = self._refresh_schedule(
-                cell_f, cell_i,
-                disable_inner=old_sched is not None and rs.disable)
+        rs.sched = self._refresh_schedule(
+            cell_f, cell_i,
+            disable_inner=old_sched is not None and rs.disable)
         rs.cell_f, rs.cell_i, rs.force = cell_f, cell_i, force
         rs.disable = False
-        rs.diags.append(jax.device_get(diag))
+        with obs_span("diag_read", self.obs):
+            rs.diags.append(jax.device_get(diag))
 
     def simulate(self, n_steps: int, state=None, collect=True,
                  on_boundary=None):
@@ -1031,34 +1048,37 @@ class MDEngine:
                 "on_boundary is incompatible with overlap_rebin: the "
                 "fused block carries its own rebin, so a boundary "
                 "mutation would run under the already-derived schedule")
-        rs = self.begin_run(state)
-        all_metrics = []
-        while rs.step < n_steps:
-            take = min(nst, n_steps - rs.step)
-            fuse = self.overlap_rebin and rs.step + take < n_steps
-            m = self.run_block(rs, take, fuse=fuse)
-            if collect:
-                all_metrics.append(jax.device_get(m))
-            if not fuse and rs.step < n_steps:
-                if on_boundary is not None:
-                    on_boundary(rs)
-                self.advance_schedule(rs)
-        cell_f, cell_i, diags = rs.cell_f, rs.cell_i, rs.diags
-        metrics = {}
-        if collect and all_metrics:
-            metrics = {k: np.concatenate([np.atleast_1d(m[k])
-                                          for m in all_metrics])
-                       for k in all_metrics[0]}
-            obs_keys = [k for k in metrics if k.startswith("obs/")]
-            if obs_keys:
-                # the traced per-step ledger counters, as one record the
-                # Perfetto exporter turns into predicted-lane counters
-                self.obs.emit("step_counters",
-                              data={k: metrics[k] for k in obs_keys})
-        self.obs.snapshot(label="md/simulate", n_steps=n_steps,
-                          backend=self.backend,
-                          pipeline=self.pipeline_mode)
-        return (cell_f, cell_i), metrics, diags
+        with obs_span("simulate", self.obs, n_steps=n_steps):
+            rs = self.begin_run(state)
+            all_metrics = []
+            while rs.step < n_steps:
+                take = min(nst, n_steps - rs.step)
+                fuse = self.overlap_rebin and rs.step + take < n_steps
+                m = self.run_block(rs, take, fuse=fuse)
+                if collect:
+                    with obs_span("metrics_read", self.obs):
+                        all_metrics.append(jax.device_get(m))
+                if not fuse and rs.step < n_steps:
+                    if on_boundary is not None:
+                        on_boundary(rs)
+                    self.advance_schedule(rs)
+            metrics = {}
+            if collect and all_metrics:
+                with obs_span("metrics_read", self.obs):
+                    metrics = {k: np.concatenate([np.atleast_1d(m[k])
+                                                  for m in all_metrics])
+                               for k in all_metrics[0]}
+            with obs_span("snapshot", self.obs):
+                obs_keys = [k for k in metrics if k.startswith("obs/")]
+                if obs_keys:
+                    # the traced per-step ledger counters, as one record
+                    # the Perfetto exporter turns into counter tracks
+                    self.obs.emit("step_counters",
+                                  data={k: metrics[k] for k in obs_keys})
+                self.obs.snapshot(label="md/simulate", n_steps=n_steps,
+                                  backend=self.backend,
+                                  pipeline=self.pipeline_mode)
+        return (rs.cell_f, rs.cell_i), metrics, rs.diags
 
     def gather_by_id(self, arrays, cell_i):
         """Host-side: reassemble per-atom arrays ordered by global id."""

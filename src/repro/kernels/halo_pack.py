@@ -187,6 +187,7 @@ def pack(src: jax.Array, index_map: jax.Array, chunk: int = 128,
                             pltpu.SemaphoreType.DMA]),
         out_shape=jax.ShapeDtypeStruct((m_pad, 1, fp), out_dtype),
         interpret=interpret,
+        name="halo_pack",
     )(_pad_index(index_map, m_pad), _as_rows(src))
     return out.reshape(m_pad, fp)[:M, :F]
 
@@ -256,6 +257,7 @@ def unpack_add(dst: jax.Array, index_map: jax.Array, rows: jax.Array,
         # operand 0 is the scalar-prefetched index map
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="halo_unpack_add",
     )(_pad_index(index_map, m_pad), _as_rows(rows), _as_rows(dst))
     return out.reshape(P_, fp)[:, :F]
 
@@ -342,6 +344,7 @@ def put_signal(src: jax.Array, index_map: jax.Array, axis: str, ring: int,
         out_shape=jax.ShapeDtypeStruct((m_pad, 1, fp), out_dtype),
         compiler_params=_remote_params(interpret),
         interpret=interpret,
+        name="halo_put_signal",
     )(_pad_index(index_map, m_pad), _as_rows(src))
     return out.reshape(m_pad, fp)[:M, :F]
 
@@ -446,5 +449,6 @@ def fused_pulses(src: jax.Array, index_maps: jax.Array, axis: str,
         out_shape=jax.ShapeDtypeStruct((n_pulses, m_pad, 1, fp), src.dtype),
         compiler_params=_remote_params(interpret),
         interpret=interpret,
+        name="halo_fused_pulses",
     )(maps, dep, ndep, _as_rows(src))
     return out.reshape(n_pulses, m_pad, fp)[:, :M, :F]

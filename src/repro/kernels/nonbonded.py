@@ -181,6 +181,7 @@ def pair_forces(a, b, ta, tb, same, ff: ForceField, block: int = 8,
                    jax.ShapeDtypeStruct((n_pad, 3, K), a.dtype),
                    jax.ShapeDtypeStruct((n_pad, 1, 1), a.dtype)],
         interpret=interpret,
+        name="nb_pair_forces",
     )(*args)
     return (jnp.swapaxes(fa[:N], 1, 2), jnp.swapaxes(fb[:N], 1, 2),
             pe[:N, 0, 0])
@@ -265,6 +266,7 @@ def scatter_accum(cell_a, cell_b, fa, fb, n_cells: int, chunk: int = 128,
                         pltpu.SemaphoreType.DMA],
         input_output_aliases={4: 0},
         interpret=interpret,
+        name="nb_scatter_accum",
     )(ia, ib, rows(fa), rows(fb), zero)
     return out.reshape(n_cells, fp)[:, :f].reshape(n_cells, K, 3)
 

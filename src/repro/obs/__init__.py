@@ -11,9 +11,10 @@ the measured trajectory.
 * :mod:`repro.obs.tracing` — ``jax.named_scope`` phase annotations,
   on-device per-step ledger counters (barrier-neutral: bitwise-identical
   trajectories with tracing on), and the host-side ``span``/``time_fn``
-  timing API shared by ``benchmarks/`` and ``launch/dryrun.py``.
+  timing API shared by ``benchmarks/`` and ``launch/dryrun.py``; spans
+  are also ``obs.<name>`` profiler annotations.
 * :mod:`repro.obs.perfetto` — metrics JSONL -> Chrome/Perfetto
-  ``trace.json`` with measured and model-predicted lanes side by side
+  ``trace.json`` of the measured host spans and counters
   (``python -m repro.obs metrics.jsonl --out trace.json``).
 * :mod:`repro.obs.gate` — drift check of a fresh
   ``BENCH_pipeline.json`` against the checked-in baseline (the CI
@@ -27,7 +28,7 @@ from repro.obs.gate import (
     compare_bench,
     gate_files,
 )
-from repro.obs.perfetto import export_trace, predicted_schedule, to_trace
+from repro.obs.perfetto import export_trace, to_trace
 from repro.obs.registry import (
     Counter,
     Gauge,
@@ -39,6 +40,7 @@ from repro.obs.registry import (
     load_jsonl,
 )
 from repro.obs.tracing import (
+    HOST_SPANS,
     NULL_TRACER,
     PHASES,
     PhaseTracer,
@@ -53,9 +55,9 @@ from repro.obs.tracing import (
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "default_registry", "iter_kind", "jsonsafe", "load_jsonl",
-    "NULL_TRACER", "PHASES", "PhaseTracer", "Span", "TimingResult",
-    "is_obs_metric", "span", "strip_obs_metrics", "time_fn",
-    "export_trace", "predicted_schedule", "to_trace",
+    "HOST_SPANS", "NULL_TRACER", "PHASES", "PhaseTracer", "Span",
+    "TimingResult", "is_obs_metric", "span", "strip_obs_metrics", "time_fn",
+    "export_trace", "to_trace",
     "DEFAULT_GATE", "KEY_FIELDS", "SCHEMA_VERSION", "cell_key",
     "compare_bench", "gate_files",
 ]

@@ -3,9 +3,9 @@
 Two subcommands (``export`` is the default when the first argument is a
 metrics JSONL path):
 
-* ``export METRICS.jsonl [--out trace.json] [--steps N]`` — build the
-  Chrome/Perfetto trace with measured + predicted lanes (open at
-  https://ui.perfetto.dev).
+* ``export METRICS.jsonl [--out trace.json]`` — build the
+  Chrome/Perfetto trace of the measured host spans and counters (open
+  at https://ui.perfetto.dev).
 * ``gate --baseline results/BENCH_pipeline.json --current NEW.json``
   — the CI drift check; exits nonzero and prints each finding when the
   current run left the baseline's tolerance envelope.
@@ -33,9 +33,6 @@ def main(argv=None) -> int:
     ex.add_argument("jsonl", help="metrics JSONL from a MetricsRegistry")
     ex.add_argument("--out", default="trace.json",
                     help="output trace path (default: trace.json)")
-    ex.add_argument("--steps", type=int, default=8,
-                    help="predicted-lane steps when no step counters "
-                         "were recorded (default: 8)")
 
     ga = sub.add_parser("gate", help="drift-check a bench file")
     ga.add_argument("--baseline", required=True,
@@ -45,7 +42,7 @@ def main(argv=None) -> int:
 
     args = ap.parse_args(argv)
     if args.cmd == "export":
-        trace = export_trace(args.jsonl, args.out, n_steps=args.steps)
+        trace = export_trace(args.jsonl, args.out)
         print(f"wrote {args.out}: {len(trace['traceEvents'])} events "
               f"({args.jsonl}: {trace['otherData']['n_records']} records)")
         return 0

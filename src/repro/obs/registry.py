@@ -22,6 +22,7 @@ dicts so the file format stays greppable and diff-able.
 """
 from __future__ import annotations
 
+import bisect
 import json
 import threading
 import time
@@ -80,32 +81,40 @@ class Gauge:
 
 
 class Histogram:
-    """Streaming distribution (span durations, per-block timings)."""
+    """Streaming distribution (span durations, per-block timings).
+
+    Values are kept sorted as they arrive, with a running sum, so
+    :meth:`state` reads its quantiles by index: a snapshot costs the
+    same whatever the number of values observed before it.
+    """
 
     kind = "histogram"
 
     def __init__(self, name: str):
         self.name = name
-        self.values: List[float] = []
+        self.values: List[float] = []     # sorted
+        self.sum = 0.0
 
     def observe(self, v: float) -> None:
-        self.values.append(float(v))
+        v = float(v)
+        bisect.insort(self.values, v)
+        self.sum += v
 
     @property
     def count(self) -> int:
         return len(self.values)
 
     def state(self) -> Any:
-        if not self.values:
-            return {"count": 0}
-        vs = sorted(self.values)
+        vs = self.values
         n = len(vs)
+        if not n:
+            return {"count": 0}
         return {
             "count": n,
-            "sum": sum(vs),
+            "sum": self.sum,
             "min": vs[0],
             "max": vs[-1],
-            "mean": sum(vs) / n,
+            "mean": self.sum / n,
             "p50": vs[n // 2],
             "p95": vs[min(n - 1, (19 * n) // 20)],
         }

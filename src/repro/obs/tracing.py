@@ -21,7 +21,10 @@ Three instruments, matched to where time can hide in the pipeline:
   ``launch/dryrun.py`` now shares.  ``span`` is a context manager whose
   ``sync()`` method pins async-dispatched device values so the clock
   stops only after the work is done (the ``md_worker`` bug class RA008
-  lints against); ``time_fn`` is the warmup+iters median loop.
+  lints against); ``time_fn`` is the warmup+iters median loop.  A span
+  is also a ``jax.profiler.TraceAnnotation`` named ``obs.<name>``, so
+  under a profiler session the host spans sit on the device trace's
+  clock beside the ``obs.<phase>`` scopes of the programs they launch.
 """
 from __future__ import annotations
 
@@ -34,18 +37,37 @@ import jax
 import jax.numpy as jnp
 
 # the phase vocabulary (paper Fig. 6 lanes); scopes are free-form but
-# these names are what the exporter and README document.
+# these names are what the trace reduction reads and the README
+# documents.
 PHASES = (
     "pack_send",          # gather halo payload + issue puts (fwd)
     "fwd_release",        # coordinate put-with-signal released
     "fwd_acquire",        # consumer's signal wait before reading halo
     "force",              # extended-block pair forces (tier ladder)
     "rev_release",        # force-return put released at fill time
+    "rev_return",         # the force-return exchange itself
     "rev_acquire",        # integrator's wait on returned forces
     "integrate_begin",    # kick-drift half step
     "integrate_finish",   # final kick
     "roll_prune",         # rolling inner prune between rebins
     "rebin_seam",         # rebin/migration gather at the block seam
+    "rebin",              # rebin/migration program body
+    "rebin_force",        # its velocity-Verlet force carry (dense pass)
+    "prune",              # pair-schedule prune program body
+)
+
+# the host spans of MDEngine's block loop (``obs.<name>`` on the
+# profiler trace; ``simulate`` is the parent of the rest)
+HOST_SPANS = (
+    "simulate",           # one MDEngine.simulate call
+    "rebin_dispatch",     # rebin_fn call
+    "prune_dispatch",     # prune_fn call
+    "schedule_read",      # prune histogram reads + tier bucketing
+    "block_dispatch",     # block program call
+    "overflow_read",      # rolling-prune overflow scalar read
+    "diag_read",          # migration diagnostics read
+    "metrics_read",       # per-step metrics read + concatenate
+    "snapshot",           # step_counters record + registry snapshot
 )
 
 
@@ -62,7 +84,14 @@ class PhaseTracer:
     enabled: bool = False
 
     def scope(self, name: str):
-        """Named scope ``obs.<name>`` for one pipeline phase."""
+        """Named scope ``obs.<name>`` for one pipeline phase.
+
+        A scope is debug metadata, which JAX's persistent compilation
+        cache leaves out of its key: an executable loaded from the cache
+        carries the scopes of the compile that stored it.  A scope added
+        to a program whose key is unchanged reaches a profile only once
+        that entry is gone.
+        """
         return jax.named_scope(f"obs.{name}")
 
     def step_metrics(self, ledger, led) -> Dict[str, jnp.ndarray]:
@@ -120,19 +149,22 @@ def span(name: str, registry=None, **meta):
     Any value passed through ``sp.sync(...)`` is blocked on before the
     stop-read, so async-dispatched device work is inside the measurement.
     With a registry, emits a ``span`` record and observes the duration in
-    the ``span/<name>`` histogram.
+    the ``span/<name>`` histogram.  The region is also the profiler
+    annotation ``obs.<name>`` (one TraceMe check with no session open).
     """
     sp = Span(name, meta)
-    sp.t0 = time.perf_counter()
-    try:
-        yield sp
-    finally:
-        if sp._sync is not None:
-            jax.block_until_ready(sp._sync)
-        sp.dur = time.perf_counter() - sp.t0
-        if registry is not None:
-            registry.emit("span", name=name, t0=sp.t0, dur=sp.dur, **meta)
-            registry.histogram(f"span/{name}").observe(sp.dur)
+    with jax.profiler.TraceAnnotation(f"obs.{name}"):
+        sp.t0 = time.perf_counter()
+        try:
+            yield sp
+        finally:
+            if sp._sync is not None:
+                jax.block_until_ready(sp._sync)
+            sp.dur = time.perf_counter() - sp.t0
+            if registry is not None:
+                registry.emit("span", name=name, t0=sp.t0, dur=sp.dur,
+                              **meta)
+                registry.histogram(f"span/{name}").observe(sp.dur)
 
 
 @dataclass

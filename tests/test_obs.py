@@ -107,6 +107,28 @@ def test_registry_snapshot_and_jsonl_roundtrip(tmp_path):
     assert snap["metrics"]["md/rows"] == {"kind": "gauge", "value": 112.0}
 
 
+def test_histogram_state_is_cheap_and_unchanged():
+    """``state()`` reads a sorted store: after 50,000 observations it
+    takes under 1 ms and gives what sorting every value gives."""
+    import time
+    vals = np.random.default_rng(3).exponential(size=50_000).tolist()
+    h = MetricsRegistry().histogram("span/x")
+    for v in vals:
+        h.observe(v)
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        got = h.state()
+        best = min(best, time.perf_counter() - t0)
+    assert best < 1e-3
+    vs, n = sorted(vals), len(vals)
+    assert {k: got[k] for k in ("count", "min", "max", "p50", "p95")} == \
+        {"count": n, "min": vs[0], "max": vs[-1], "p50": vs[n // 2],
+         "p95": vs[(19 * n) // 20]}
+    assert got["sum"] == pytest.approx(sum(vs), rel=1e-12)
+    assert got["mean"] == pytest.approx(sum(vs) / n, rel=1e-12)
+
+
 def test_default_registry_is_a_singleton():
     assert default_registry() is default_registry()
 
@@ -202,6 +224,41 @@ def test_engine_publishes_structured_records():
     # pair_stats() runs after the simulate snapshot: gauge is live-only
     assert reg.metrics()["md/prune_ratio"] >= 1.0
     json.dumps(reg.records)
+
+
+def test_engine_programs_carry_scopes_and_kernel_names():
+    """The rebin and prune programs carry their ``obs.*`` scopes and the
+    block program the NB kernel's name, where a profiler trace reads
+    them (the lowered text's locations hold the scope paths)."""
+    from repro.core.md import MDEngine, make_grappa_like
+
+    eng = MDEngine(make_grappa_like(200, seed=5),
+                   make_mesh((1, 1, 1), ("z", "y", "x")),
+                   force_backend="pallas", static_ladder=True)
+    cf, ci = eng.init_state()
+
+    def text(fn, *args):
+        return fn.lower(*args).as_text(debug_info=True)
+
+    rebin = text(eng.rebin_fn, cf, ci)
+    assert "obs.rebin/" in rebin and "obs.rebin/obs.rebin_force/" in rebin
+    assert "obs.prune/" in text(eng.prune_fn, cf, ci)
+    rs = eng.begin_run((cf, ci))
+    sel, tiers, tiers_inner = rs.sched
+    block = text(eng.block_sched_fn, rs.cell_f, rs.cell_i, rs.force, sel,
+                 4, tiers, tiers_inner)
+    assert "obs.force/nb_pair_forces/" in block
+
+
+def test_simulate_host_spans_reach_the_profiler_trace(dist):
+    """On 4 virtual CPU devices, under ``jax.profiler.trace``, the
+    engine's spans are ``obs.*`` host events nested in ``obs.simulate``."""
+    out = dist("check_obs_spans.py", devices=4, timeout=300)
+    assert "check_obs_spans OK" in out
+    names = set(out.splitlines()[-2].split())
+    assert {"obs.simulate", "obs.schedule_read", "obs.metrics_read",
+            "obs.rebin_dispatch", "obs.prune_dispatch",
+            "obs.block_dispatch", "obs.diag_read", "obs.snapshot"} <= names
 
 
 def test_ledger_summary_publishes_gauges():
@@ -318,19 +375,23 @@ def test_perfetto_export_matches_golden(tmp_path):
 def test_perfetto_trace_structure():
     trace = to_trace(load_jsonl(FIXTURES / "sample.jsonl"))
     evs = trace["traceEvents"]
-    assert sorted({e["pid"] for e in evs}) == [0, 1]   # measured+predicted
+    assert {e["pid"] for e in evs} == {0}              # the measured lane
     for e in evs:
         assert e["ph"] in ("M", "X", "C")
         if e["ph"] == "X":
             assert e["dur"] > 0 and e["ts"] >= 0
-    names = {e["name"] for e in evs if e["ph"] == "X" and e["pid"] == 1}
-    assert {"fwd halo", "rev halo", "force + integrate",
-            "overlapped halo"} <= names
-    # 8 recorded steps drive the predicted lane, not the default
-    assert sum(1 for e in evs
-               if e["ph"] == "X" and e["name"] == "fwd halo") == 8
-    counters = {e["name"] for e in evs if e["ph"] == "C" and e["pid"] == 1}
-    assert {"obs/in_flight", "obs/clobbers"} <= counters
+    names = {e["name"] for e in evs if e["ph"] == "X"}
+    assert names == {"rebin_dispatch", "block_dispatch"}
+    # the 8 recorded steps' ledger counters, spread evenly up to the
+    # record's time (0.8 s after the first span's start)
+    steps = [e for e in evs
+             if e["ph"] == "C" and e["name"] == "obs/in_flight"]
+    assert [e["args"]["obs/in_flight"] for e in steps] == \
+        [1, 2, 2, 2, 2, 2, 2, 1]
+    assert [e["ts"] for e in steps] == \
+        pytest.approx([k * 0.1e6 for k in range(8)])
+    counters = {e["name"] for e in evs if e["ph"] == "C"}
+    assert {"obs/in_flight", "obs/clobbers", "md/steps"} <= counters
     assert trace["otherData"]["backend"] == "signal"
 
 
@@ -340,8 +401,11 @@ def test_perfetto_export_from_live_registry(tmp_path):
     reg.to_jsonl(p)
     trace = export_trace(p, tmp_path / "trace.json")
     evs = trace["traceEvents"]
-    assert sorted({e["pid"] for e in evs}) == [0, 1]
-    assert any(e["ph"] == "X" and e["pid"] == 0 for e in evs)
+    assert {e["pid"] for e in evs} == {0}
+    spans = {e["name"] for e in evs if e["ph"] == "X"}
+    assert {"simulate", "schedule_read", "metrics_read"} <= spans
+    counters = {e["name"] for e in evs if e["ph"] == "C"}
+    assert {"obs/in_flight", "obs/clobbers"} <= counters
     json.dumps(trace)
 
 
