@@ -60,7 +60,13 @@ from repro.core.md.pair_schedule import (
     prune_radius,
     roll_prune,
 )
-from repro.core.md.schedule_opt import bucket, tier_cum, tier_plan, tier_rows
+from repro.core.md.schedule_opt import (
+    bucket,
+    tier_cum,
+    tier_plan,
+    tier_rows,
+    tier_slot_pairs,
+)
 from repro.core.md.schedule_opt import noop  # critical-path opt hook (§5.4)
 from repro.core.md.system import MDSystem
 from repro.core.pipeline import PIPELINE_MODES, StepFns, StepPipeline
@@ -271,6 +277,11 @@ class MDEngine:
         if force_backend != "dense":
             self.pair_schedule = PairSchedule.build(self.layout)
             self._pair_stats = self.pair_schedule.slot_pair_stats()
+            # the rebin's force carry: the whole unpruned worklist on the
+            # worst-case ladder (exact, no prune or host read needed)
+            self._carry_tiers = self.pair_schedule.full_tiers(
+                self.pair_bucket)
+            carry_slot_pairs = tier_slot_pairs(self._carry_tiers)
         else:
             # dense never builds a worklist (degenerate one-global-cell
             # layouts stay supported); mirror its accounting directly
@@ -283,7 +294,12 @@ class MDEngine:
                 "evaluated_slot_pairs": n_dense * self.layout.capacity ** 2,
                 "prune_ratio": 1.0,
             }
+            carry_slot_pairs = self._pair_stats["dense_slot_pairs"]
         self._pair_stats["force_backend"] = force_backend
+        # which evaluator the rebin's force carry runs, and its slot pairs
+        # per domain (one pass per rebin)
+        self._carry_stats = {"carry_backend": force_backend,
+                             "carry_slot_pairs": carry_slot_pairs}
         dt = system.pos.dtype
         if spec.wrap_shift is None:
             ws = np.zeros((3, 4), dt)
@@ -336,7 +352,8 @@ class MDEngine:
             n_atoms=system.n_atoms, global_cells=self.layout.global_cells,
             capacity=self.layout.capacity,
             schedule_safe=(None if self.schedule_report is None
-                           else self.schedule_report.safe))
+                           else self.schedule_report.safe),
+            **self._carry_stats)
         self._build_programs()
 
     @property
@@ -370,9 +387,11 @@ class MDEngine:
         """Evaluated-slot-pair accounting of the latest pruned block.
 
         Per domain per step; ``prune_ratio`` is the dense-over-evaluated
-        work reduction (1.0 for the dense backend).
+        work reduction (1.0 for the dense backend).  ``carry_backend`` /
+        ``carry_slot_pairs`` describe the rebin's force carry (per domain,
+        once per rebin).
         """
-        out = dict(self._pair_stats)
+        out = {**self._pair_stats, **self._carry_stats}
         if self.nstprune:
             # live counter, not the last _bucket_exec's snapshot: a
             # final block's overflow has no further rebin to record it
@@ -407,33 +426,47 @@ class MDEngine:
         F = jnp.zeros(tuple(ext_shape[:3]) + F_trim.shape[3:], F_trim.dtype)
         return F.at[tuple(slice(0, n[d] + 1) for d in range(3))].set(F_trim)
 
-    def _force_pass(self, cell_f, cell_i):
+    def _force_pass(self, cell_f, cell_i, forces=None):
         """Coordinate halo -> forces -> force halo (paper Alg. 3/6).
 
+        ``forces(ext_f_trim, ext_i_trim)`` evaluates the NB forces on the
+        trimmed extended block; the default is the dense 14-zone pass.
         Runs inside the engine's shard_map, so the plan's device-local
         methods are used; gradients through this pass would follow the
         plan's fused reverse path (``HaloPlan.exchange``).
         """
         ext_f = self.plan.fwd_local(cell_f[..., :4])
         ext_i = self.plan.fwd_local(cell_i, wrap_shift=None)
-        F_trim, pe = compute_forces(self._trim_ext(ext_f),
-                                    self._trim_ext(ext_i), self.layout,
-                                    self.system.params.ff)
+        if forces is None:
+            def forces(ef, ei):
+                return compute_forces(ef, ei, self.layout,
+                                      self.system.params.ff)
+        F_trim, pe = forces(self._trim_ext(ext_f), self._trim_ext(ext_i))
         f_local = self.plan.rev_local(self._pad_force(F_trim, ext_f.shape))
         return f_local, lax.psum(pe, AXES)
 
     def _force_pass_sched(self, cell_f, cell_i, sel, tiers):
         """Schedule-driven force pass (device-local, pruned backends)."""
-        ext_f = self.plan.fwd_local(cell_f[..., :4])
-        ext_i = self.plan.fwd_local(cell_i, wrap_shift=None)
         backend_fn = get_force_backend(self.force_backend)
-        F_trim, pe = backend_fn(
-            self._trim_ext(ext_f), self._trim_ext(ext_i), self.layout,
-            self.system.params.ff, sched=self.pair_schedule,
-            sel=lax.slice(sel.reshape(-1), (0,), (tier_rows(tiers),)),
-            tiers=tiers)
-        f_local = self.plan.rev_local(self._pad_force(F_trim, ext_f.shape))
-        return f_local, lax.psum(pe, AXES)
+
+        def forces(ef, ei):
+            return backend_fn(
+                ef, ei, self.layout, self.system.params.ff,
+                sched=self.pair_schedule,
+                sel=lax.slice(sel.reshape(-1), (0,), (tier_rows(tiers),)),
+                tiers=tiers)
+        return self._force_pass(cell_f, cell_i, forces)
+
+    def _carry_pass(self, cell_f, cell_i):
+        """The rebin's velocity-Verlet force carry, by the engine's own
+        backend: the dense pass for ``dense``, else the full unpruned
+        worklist on the worst-case ladder (every eighth-shell pair, masked
+        at ``r_cut`` by the kernel — the same physics as the dense pass)."""
+        if self.force_backend == "dense":
+            return self._force_pass(cell_f, cell_i)
+        sel = jnp.arange(self.pair_schedule.n_pairs, dtype=jnp.int32)
+        return self._force_pass_sched(cell_f, cell_i, sel,
+                                      self._carry_tiers)
 
     # ---- step physics, split at the halo seams (StepFns) -------------------
 
@@ -625,7 +658,7 @@ class MDEngine:
             with sc("rebin"):
                 new_f, new_i, diag = rebin(cell_f, cell_i, layout, mig_cap)
                 with sc("rebin_force"):
-                    force, pe = self._force_pass(new_f[..., :4], new_i)
+                    force, pe = self._carry_pass(new_f[..., :4], new_i)
                     force = jnp.where(new_i[..., 0:1] >= 0, force, 0.0)
             return new_f, new_i, force, diag
 

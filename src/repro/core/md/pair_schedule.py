@@ -87,7 +87,7 @@ from jax import lax
 from repro.core.md.cells import CellLayout, cell_bounds, cell_counts, \
     cell_levels
 from repro.core.md.forces import compute_forces, pair_terms
-from repro.core.md.schedule_opt import tier_rows, tier_slot_pairs
+from repro.core.md.schedule_opt import tier_plan, tier_rows, tier_slot_pairs
 from repro.core.md.system import ForceField, MDParams
 
 # exec-shape quanta: surviving pair counts bucket to multiples of
@@ -170,6 +170,16 @@ class PairSchedule:
     def levels(self) -> int:
         """Occupancy-level count of this layout's tier ladders."""
         return n_levels(self.layout.capacity)
+
+    def full_tiers(self, pair_bucket: int):
+        """The data-independent worst-case ladder: every worklist row at
+        the deepest level, one ``(M, K)`` tier.  Evaluated over
+        ``sel = arange(M)`` it covers every eighth-shell pair, so it
+        needs no prune (the ``static_ladder`` block ladder and the
+        rebin's force carry)."""
+        M = self.n_pairs
+        return tier_plan([M] * self.levels, pair_bucket, M, SLOT_QUANTUM,
+                         self.layout.capacity)
 
     def dense_slot_pairs(self) -> int:
         """Slot pairs the dense engine evaluates per domain per step."""

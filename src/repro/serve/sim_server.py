@@ -46,8 +46,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.compat import shard_map_norep
 from repro.core.md.domain import AXES
 from repro.core.md.engine import MDEngine
-from repro.core.md.pair_schedule import SLOT_QUANTUM
-from repro.core.md.schedule_opt import tier_plan
 from repro.core.md.system import MDSystem
 from repro.launch.mesh import make_mesh
 from repro.obs import MetricsRegistry
@@ -214,13 +212,9 @@ class SimServer:
         nst = self.block_steps
         counter = self.obs.counter("serve/compiles")
         if tmpl.force_backend != "dense":
-            M = tmpl.pair_schedule.n_pairs
-            L = tmpl.pair_schedule.levels
-            K = tmpl.layout.capacity
             # static worst-case ladder: every lane, every block runs the
             # same (M, K) tier — data-independent shapes, inert sentinels
-            tiers = tier_plan([M] * L, tmpl.pair_bucket, M,
-                              SLOT_QUANTUM, K)
+            tiers = tmpl.pair_schedule.full_tiers(tmpl.pair_bucket)
 
             def body(cf, ci, force, sel):
                 counter.inc()          # trace-time only: 1 per compile

@@ -106,9 +106,11 @@ def main():
     # round-off accumulated over 24 steps, energies tighter)
     pos_ref, vel_ref = eng_ref.gather_by_id(
         [cf_ref[..., 0:3], cf_ref[..., 4:7]], ci_ref)
+    pruned = {}
     for fb in ("sparse", "pallas"):
         cf, ci, m, _, eng = run(system, mesh, "serialized", "off", n_steps,
                                 force_backend=fb)
+        pruned[fb] = (cf, ci, m)
         pos, vel = eng.gather_by_id([cf[..., 0:3], cf[..., 4:7]], ci)
         dpos = np.abs(pos - pos_ref).max()
         dvel = np.abs(vel - vel_ref).max()
@@ -121,6 +123,17 @@ def main():
         print(f"force_backend={fb}: 24-step trajectory within tolerance "
               f"(dpos {dpos:.1e}, dpe {rel_pe:.1e}), "
               f"prune ratio {ratio:.2f}x")
+
+    # the fused overlap_rebin program carries the same Pallas-kernel force
+    # carry as the host-dispatched rebin: bitwise-identical trajectories
+    cf_p, ci_p, m_p = pruned["pallas"]
+    cf, ci, m, _, _ = run(system, mesh, "serialized", "off", n_steps,
+                          force_backend="pallas", overlap_rebin=True)
+    assert np.array_equal(cf, cf_p) and np.array_equal(ci, ci_p), \
+        "pallas/ovr trajectory differs from pallas/off"
+    for k in m_p:
+        assert np.array_equal(m[k], m_p[k]), ("pallas/ovr", k)
+    print("pallas/off/ovr == pallas/off bitwise")
 
     # --- pruned backend under the step pipeline: schedule threading ----
     # sparse/off, sparse/double_buffer (any depth), and the fused

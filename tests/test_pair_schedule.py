@@ -542,3 +542,98 @@ def test_sparse_engine_matches_direct_oracle():
     # the tier ladder beats (or matches) the single-rectangle schedule
     ps = eng.pair_stats()
     assert ps["evaluated_slot_pairs"] <= ps["global_kexec_slot_pairs"]
+
+
+# ---- the rebin's force carry: the engine's own backend --------------------
+
+def _carry_engine(force_backend, n=300, seed=11, **kw):
+    from repro.core.halo_plan import HaloSpec
+    from repro.core.md import MDEngine
+    from repro.launch.mesh import make_mesh
+
+    sys_ = make_grappa_like(n, seed=seed)
+    spec = HaloSpec(axis_names=("z", "y", "x"), widths=(1, 1, 1),
+                    backend="fused")
+    return sys_, MDEngine(sys_, make_mesh((1, 1, 1), ("z", "y", "x")), spec,
+                          force_backend=force_backend, **kw)
+
+
+@pytest.mark.parametrize("fb", ["sparse", "pallas"])
+def test_pruned_rebin_carry_matches_direct_oracle(fb):
+    """The carry of a pruned-backend engine (the full worklist on the
+    worst-case ladder, no prune) is the exact force: it matches the
+    O(N^2) oracle like the dense carry does, and sums to zero."""
+    from repro.core.md import direct_forces_reference
+
+    sys_, eng = _carry_engine(fb)
+    cf, ci = eng.init_state()
+    cf, ci, force, diag = eng.rebin_fn(cf, ci)
+    assert int(np.asarray(diag["bin_overflow"])) == 0
+    f_eng, = eng.gather_by_id([force], ci)
+    f_ref, _ = direct_forces_reference(sys_.pos, sys_.charge, sys_.typ,
+                                       sys_.box, sys_.params.ff)
+    scale = np.abs(f_ref).max()
+    assert np.abs(f_eng - f_ref).max() / scale < 5e-5
+    assert np.abs(f_eng.sum(axis=0)).max() < 1e-3
+
+
+def test_pruned_engines_never_run_the_dense_pass(monkeypatch):
+    """With the dense 14-zone pass made to raise, a pruned engine opens a
+    run and crosses two rebins (begin_run and one interior boundary);
+    a dense engine, and one the tiny-box path degraded to dense, still
+    trace it for their carry."""
+    from repro.core.md import engine as engine_mod
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("dense 14-zone pass traced")
+
+    monkeypatch.setattr(engine_mod, "compute_forces", boom)
+    _, eng = _carry_engine("sparse", n=200, seed=5, static_ladder=True)
+    eng.begin_run()
+    (cf, ci), m, diags = eng.simulate(40)
+    assert len(diags) == 2
+    assert np.all(np.isfinite(np.asarray(m["pe"])))
+
+    _, dense = _carry_engine("dense", n=200, seed=5)
+    with pytest.warns(RuntimeWarning, match="degrades to the 'dense'"):
+        _, degraded = _carry_engine("sparse", n=110, seed=3)
+    for e in (dense, degraded):
+        cf, ci = e.init_state()
+        with pytest.raises(RuntimeError, match="dense 14-zone pass"):
+            e.rebin_fn(cf, ci)
+
+
+def test_dense_rebin_carry_is_the_dense_force_pass():
+    """A dense engine's carry is bitwise the standalone dense force pass
+    on the rebinned state (empty slots zeroed)."""
+    _, eng = _carry_engine("dense")
+    cf, ci = eng.init_state()
+    cf, ci, force, _ = eng.rebin_fn(cf, ci)
+    f_ref, _ = eng._force_fn_dense(cf, ci)
+    f_ref = jnp.where(ci[..., 0:1] >= 0, f_ref, 0.0)
+    np.testing.assert_array_equal(np.asarray(force), np.asarray(f_ref))
+
+
+@pytest.mark.parametrize("fb", ["dense", "sparse", "pallas"])
+def test_pair_stats_publish_the_carry(fb):
+    """``pair_stats()`` and the ``engine_build`` record name the carry's
+    evaluator and its slot pairs per domain: the full (M, K) tier for
+    the pruned backends, every dense slot pair for ``dense``."""
+    from repro.obs import MetricsRegistry
+
+    reg = MetricsRegistry()
+    _, eng = _carry_engine(fb, n=200, seed=5, obs=reg)
+    ps = eng.pair_stats()
+    assert ps["carry_backend"] == fb
+    assert ps["carry_slot_pairs"] == ps["dense_slot_pairs"] == \
+        14 * eng.layout.n_local_cells * eng.layout.capacity ** 2
+    build = [r for r in reg.records if r["kind"] == "engine_build"][0]
+    assert build["carry_backend"] == fb
+    assert build["carry_slot_pairs"] == ps["carry_slot_pairs"]
+    if fb != "dense":
+        assert eng._carry_tiers == ((eng.pair_schedule.n_pairs,
+                                     eng.layout.capacity),)
+        # still published after a prune replaces the block accounting
+        eng.begin_run()
+        assert eng.pair_stats()["carry_slot_pairs"] == \
+            ps["carry_slot_pairs"]

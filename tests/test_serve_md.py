@@ -40,7 +40,7 @@ BUCKET = 256        # canonical atom bucket for most cells
 R0, R1, R2 = (200, 5), (256, 7), (230, 9)
 
 MATRIX = [(fb, pipe) for fb in ("dense", "sparse")
-          for pipe in ("off", "double_buffer")]
+          for pipe in ("off", "double_buffer")] + [("pallas", "off")]
 
 
 @functools.lru_cache(maxsize=None)
