@@ -83,19 +83,16 @@ class CompileCounter:
 # --------------------------------------------------------------------------
 
 def build(config: dict, chips: int, seed: int):
-    """The system from ``seed`` (by the benchmark's own generator) and the
+    """The system from ``seed`` (by the benchmark's own generator), its
+    force field from the configuration's ``force_field`` kind, and the
     engine on ``make_md_mesh(chips)``."""
     from repro.core.md import MDEngine
-    from repro.core.md.system import ForceField, MDParams, MDSystem
+    from repro.core.md.system import MDParams, MDSystem
     from repro.launch.mesh import make_md_mesh
     ref = spec.reference(config)
     arrays = ref.make_system(config, seed)
     dt = np.dtype(config["dtype"])
-    ff = ForceField(eps=tuple(map(tuple, config["eps"])),
-                    sigma=tuple(map(tuple, config["sigma"])),
-                    r_cut=float(config["r_cut"]),
-                    eps_rf=float(config["eps_rf"]))
-    params = MDParams(ff=ff, dt=float(config["dt"]),
+    params = MDParams(ff=spec.force_field(config), dt=float(config["dt"]),
                       mass=float(config["mass"]),
                       nstlist=int(config["nstlist"]),
                       temperature=float(config["temperature"]))
@@ -191,7 +188,8 @@ def reference_chain(ref, config, arrays, start: dict, n_steps: int,
 def compare_chain(box, got: list, want: list) -> dict:
     """The numbers compared, each the largest over the chained blocks:
 
-    * ``pos_err``: largest minimum-image position difference (sigma);
+    * ``pos_err``: largest minimum-image position difference, in the
+      configuration's length unit;
     * ``vel_err``: largest velocity difference over the largest
       reference velocity component;
     * ``pe_err``: largest per-step potential-energy difference per atom.

@@ -5,5 +5,5 @@ import host_idle
 
 
 def read(ctx):
-    spans = [s for s in host_idle.host_spans() if s[2] == "obs.simulate"]
+    spans = [s for s in host_idle.host_spans(ctx) if s[2] == "obs.simulate"]
     return host_idle.idle_ms_per_block(ctx.reduced, spans, "obs.simulate")

@@ -6,5 +6,5 @@ import host_idle
 
 
 def read(ctx):
-    return host_idle.idle_ms_per_block(ctx.reduced, host_idle.host_spans(),
+    return host_idle.idle_ms_per_block(ctx.reduced, host_idle.host_spans(ctx),
                                        "obs.schedule_read")

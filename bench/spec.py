@@ -1,11 +1,17 @@
-"""Find a cell's configuration, traffic mix and metric readers by name.
+"""Find a cell's configuration, force field, traffic mix and metric
+readers by name.
 
-Everything that belongs to one configuration, one traffic mix or one
-per-layer metric lives in a file of its own, so adding one adds a file:
+Everything that belongs to one configuration, one force field, one
+traffic mix or one per-layer metric lives in a file of its own, so
+adding one adds a file:
 
 * a configuration is the JSON file named by its ``configs`` entry in
   ``BENCHMARK.json``, with its plain reference at
   ``references/<reference>.py`` beside this module;
+* a force field is ``force_fields/<kind>.py``, a module with
+  ``build(config)`` that returns the program's force field for a
+  configuration whose ``force_field`` names that kind (there is no
+  default kind);
 * a traffic mix is ``traffic/<name>.json``;
 * a per-layer metric is ``metrics/<name>.py``, a module with
   ``read(ctx)`` that returns a number, or ``None`` where it finds
@@ -48,8 +54,8 @@ def resolve_cell(bench: dict, workload: str, root: Path = ROOT,
     cfg_entry = _by_name(bench["configs"], cell["config"], "configuration")
     config = _json(Path(root) / cfg_entry["file"])
     # JSON has no infinity: a configuration spells it "inf"
-    if isinstance(config.get("eps_rf"), str):
-        config["eps_rf"] = float(config["eps_rf"])
+    config.update({k: float(v) for k, v in config.items()
+                   if v in ("inf", "-inf")})
     traffic = _json(Path(bench_dir) / "traffic" / f"{cell['traffic']}.json")
 
     def applies(m):
@@ -67,7 +73,8 @@ def resolve_cell(bench: dict, workload: str, root: Path = ROOT,
 def load_module(path: Path, name: str):
     spec = importlib.util.spec_from_file_location(name, path)
     if spec is None or not path.is_file():
-        raise SystemExit(f"bench: no module at {path}")
+        known = sorted(p.stem for p in Path(path).parent.glob("*.py"))
+        raise SystemExit(f"bench: no module at {path}; known: {known}")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -85,3 +92,15 @@ def reference(config: dict, bench_dir: Path = BENCH_DIR):
     name = config["reference"]
     return load_module(Path(bench_dir) / "references" / f"{name}.py",
                        f"bench_reference_{name}")
+
+
+def force_field(config: dict, bench_dir: Path = BENCH_DIR):
+    """The program's force field for ``config``: ``build(config)`` of
+    ``force_fields/<kind>.py``, the kind that its ``force_field`` names."""
+    if "force_field" not in config:
+        raise SystemExit(f"bench: configuration {config.get('name')!r} "
+                         f"names no force_field")
+    kind = config["force_field"]
+    mod = load_module(Path(bench_dir) / "force_fields" / f"{kind}.py",
+                      f"bench_force_field_{kind}")
+    return mod.build(config)

@@ -6,8 +6,9 @@ Each device is a process ``/device:TPU:<n>`` whose ``XLA Ops`` thread
 holds one event per device operation (a ``while`` op spans its body's
 ops), ``Async XLA Ops`` the asynchronous copies and collectives in
 flight, and ``XLA Modules`` one event per program execution.  The host
-process ``/host:CPU`` holds the harness's ``bench.*`` annotations, on the
-same clock.
+process ``/host:CPU`` holds the harness's ``bench.*`` annotations and the
+program's own ``obs.*`` host spans (``repro.obs.span``), on the same
+clock.
 
 An operation is attributed to a step-pipeline phase by the innermost
 ``obs.<phase>`` named scope in its ``tf_op`` scope path; an operation
@@ -16,6 +17,7 @@ with no such scope is ``other``.
 from __future__ import annotations
 
 import gzip
+import heapq
 import json
 import re
 from pathlib import Path
@@ -86,6 +88,36 @@ def leaves(ops):
             if nxt is None or not (nxt[0] < op[1] and nxt[1] <= op[1])]
 
 
+def idle_by_span(idle, spans) -> dict:
+    """``{span name: ns}`` of the merged, sorted ``idle`` intervals under
+    the innermost of ``spans`` (``(start, end, name)``): each interval is
+    cut at the spans' edges and each piece goes to the span that covers
+    it and started last (of two that started together, the one that ends
+    first); the pieces under no span go to ``None``.  One sorted sweep
+    over the intervals and the spans together."""
+    spans = sorted(spans)
+    out, active, j = {}, [], 0
+    for lo, hi in idle:
+        cur = lo
+        while cur < hi:
+            while j < len(spans) and spans[j][0] <= cur:
+                s, e, name = spans[j]
+                heapq.heappush(active, (-s, e, name))
+                j += 1
+            while active and active[0][1] <= cur:
+                heapq.heappop(active)
+            nxt = hi
+            if j < len(spans):
+                nxt = min(nxt, spans[j][0])
+            owner = None
+            if active:
+                owner = active[0][2]
+                nxt = min(nxt, active[0][1])
+            out[owner] = out.get(owner, 0) + (nxt - cur)
+            cur = nxt
+    return out
+
+
 def phase_of(text: str) -> str:
     """Innermost ``obs.<phase>`` scope named in ``text``, else ``other``."""
     found = _SCOPE.findall(text or "")
@@ -104,7 +136,8 @@ class Reduced:
     end_ns, name, phase)``, where ``name`` is the op's scope path;
     ``async_ops[d]``: its asynchronous operations, alike;
     ``modules[d]``: ``(start_ns, end_ns, program_name)``;
-    ``host``: ``(start_ns, end_ns, annotation)``.
+    ``host``: the harness's ``bench.*`` annotations as ``(start_ns,
+    end_ns, name)``; ``spans``: the program's ``obs.*`` host spans, alike.
 
     The traced window ``[t0, t1)`` is the host's ``bench.window``, cut to
     the block programs that every device's trace holds: from the latest
@@ -117,7 +150,7 @@ class Reduced:
     Device items are clipped to ``[t0, t1)``.
     """
 
-    def __init__(self, ops, async_ops, modules, host, t0, t1):
+    def __init__(self, ops, async_ops, modules, host, t0, t1, spans=()):
         runs = [sorted((s, e) for s, e, n in clip(v, t0, t1)
                        if BLOCK_MODULE.search(n))
                 for v in modules.values()]
@@ -132,6 +165,7 @@ class Reduced:
         self.async_ops = {d: clip(v, t0, t1) for d, v in async_ops.items()}
         self.modules = {d: clip(v, t0, t1) for d, v in modules.items()}
         self.host = host
+        self.spans = list(spans)
         self.devices = sorted(self.ops)
 
     @property
@@ -181,7 +215,7 @@ class Reduced:
     def breakdown(self, top: int = 10) -> dict:
         """The ops that took most device time (by scope path, summed over
         devices, seconds), and the longest idle gaps of device 0, each
-        labelled by the host annotation that covers most of it."""
+        labelled by :meth:`gap_label`."""
         total = {}
         for d in self.devices:
             for s, e, n, _p in self.ops[d]:
@@ -190,14 +224,24 @@ class Reduced:
         idle = []
         if self.devices:
             busy = self._cover(self.ops[self.devices[0]])
-            idle = [(self.host_label(s, e), (e - s) / 1e9)
-                    for s, e in gaps(busy, self.t0, self.t1)]
-        idle.sort(key=lambda kv: -kv[1])
-        return {"device_ops": [[n, v] for n, v in ops],
-                "idle_gaps": [[n, v] for n, v in idle[:top]]}
+            longest = sorted(gaps(busy, self.t0, self.t1),
+                             key=lambda g: g[0] - g[1])[:top]
+            idle = [[self.gap_label(s, e), (e - s) / 1e9]
+                    for s, e in longest]
+        return {"device_ops": [[n, v] for n, v in ops], "idle_gaps": idle}
+
+    def gap_label(self, s, e) -> str:
+        """The ``obs.*`` host span that is innermost over most of [s, e)
+        (:func:`idle_by_span`); where none is, the ``bench.*`` annotation
+        of :meth:`host_label`."""
+        owners = idle_by_span([(s, e)], self.spans)
+        owners.pop(None, None)
+        if owners:
+            return max(owners, key=owners.get)
+        return self.host_label(s, e)
 
     def host_label(self, s, e) -> str:
-        """The innermost host annotation that overlaps [s, e) most."""
+        """The innermost ``bench.*`` annotation that overlaps [s, e) most."""
         best, best_key = "host", (0, 0)
         for hs, he, name in self.host:
             ov = min(e, he) - max(s, hs)
@@ -216,14 +260,15 @@ class Reduced:
 def from_events(events) -> Reduced:
     """Build a :class:`Reduced` from Chrome-trace events: ``M`` events
     name processes and threads, ``X`` events carry ``ts`` and ``dur`` in
-    microseconds and ``args``."""
+    microseconds and ``args``.  The one parse of a run's trace: the host
+    spans that the readers attribute idle time to come from here too."""
     proc, thread = {}, {}
     for e in events:
         if e.get("ph") == "M" and e.get("name") == "process_name":
             proc[e["pid"]] = e["args"]["name"]
         elif e.get("ph") == "M" and e.get("name") == "thread_name":
             thread[(e["pid"], e["tid"])] = e["args"]["name"]
-    ops, async_ops, modules, host = {}, {}, {}, []
+    ops, async_ops, modules, host, spans = {}, {}, {}, [], []
     for e in events:
         if e.get("ph") != "X":
             continue
@@ -243,10 +288,12 @@ def from_events(events) -> Reduced:
                 modules.setdefault(d, []).append((s, t, e["name"]))
         elif pname.startswith("/host:") and e["name"].startswith("bench."):
             host.append((s, t, e["name"]))
+        elif pname.startswith("/host:") and e["name"].startswith("obs."):
+            spans.append((s, t, e["name"]))
     windows = [(s, t) for s, t, n in host if n == "bench.window"]
     if not windows:
         raise ValueError("trace has no bench.window annotation")
-    return Reduced(ops, async_ops, modules, host, *windows[0])
+    return Reduced(ops, async_ops, modules, host, *windows[0], spans=spans)
 
 
 def load(trace_dir) -> Reduced:

@@ -2,7 +2,6 @@
 programs' ``obs.*`` scopes, and device idle time attributed to the
 engine's ``obs.*`` host spans, on small traces whose answers are known;
 and the recorded trace, on which every earlier reading stays as it was."""
-import gzip
 import json
 import tempfile
 from types import SimpleNamespace
@@ -87,16 +86,10 @@ IDLE_BY_SPAN = {"obs.rebin_dispatch": 20, "obs.prune_dispatch": 10,
 
 
 @pytest.fixture
-def traced(tmp_path, monkeypatch):
-    """The synthetic trace where the harness keeps a run's trace, and
-    the reader context of that run."""
-    ev = events(OPS, ASYNC, MODULES, HOST)
-    d = tmp_path / "bench-trace-x" / "plugins" / "profile" / "t"
-    d.mkdir(parents=True)
-    with gzip.open(d / "host.trace.json.gz", "wt") as fh:
-        json.dump({"traceEvents": ev}, fh)
-    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
-    r = tr.from_events(ev)
+def traced():
+    """The reader context of a run whose trace is the synthetic one: the
+    readers take the host spans from its reduced trace."""
+    r = tr.from_events(events(OPS, ASYNC, MODULES, HOST))
     return SimpleNamespace(reduced=r, steps=3 * 20, blocks=r.blocks)
 
 
@@ -117,7 +110,7 @@ def test_rebin_and_prune_scope_metrics(traced):
 
 
 def test_idle_goes_to_the_innermost_host_span(traced):
-    spans = host_idle.host_spans()
+    spans = host_idle.host_spans(traced)
     assert {n for _s, _e, n in spans} == {n for _s, _e, n in HOST
                                           if n.startswith("obs.")}
     idle = host_idle.idle_intervals(traced.reduced, 0)
@@ -171,11 +164,11 @@ def test_idle_by_span_matches_brute_force():
     assert host_idle.idle_by_span(idle, spans) == want
 
 
-def test_no_trace_and_no_spans_read_nothing(tmp_path, monkeypatch):
-    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
-    assert host_idle.trace_file() is None and host_idle.host_spans() == []
+def test_no_trace_and_no_spans_read_nothing():
+    assert host_idle.host_spans(SimpleNamespace(reduced=None)) == []
     r = tr.from_events(events(OPS, ASYNC, MODULES, HOST[:2]))
     ctx = SimpleNamespace(reduced=r, steps=60, blocks=3)
+    assert host_idle.host_spans(ctx) == []
     for m in NEW[3:]:
         assert spec.metric_reader(m)(ctx) is None
     assert host_idle.idle_ms_per_block(None, HOST, "obs.simulate") is None

@@ -28,6 +28,41 @@ def test_fixture_entries_are_found_by_name():
         "fixture reference"
 
 
+def test_force_field_kind_is_found_by_name():
+    """A configuration's ``force_field`` names a file of
+    ``force_fields/``; a kind with no file, and a configuration that
+    names none, are refused by name (there is no default kind)."""
+    bench = json.loads((FIX / "BENCHMARK.json").read_text())
+    cfg = spec.resolve_cell(bench, "fixture-cfg.fixture_mix", root=FIX,
+                            bench_dir=FIX)["config"]
+    assert spec.force_field(cfg, bench_dir=FIX) == ("fixture kind", 1.5)
+    with pytest.raises(SystemExit, match="no_such_kind.*fixture_kind"):
+        spec.force_field(dict(cfg, force_field="no_such_kind"),
+                         bench_dir=FIX)
+    bare = {k: v for k, v in cfg.items() if k != "force_field"}
+    with pytest.raises(SystemExit, match="fixture-cfg.*force_field"):
+        spec.force_field(bare, bench_dir=FIX)
+
+
+def test_lj_rf_builds_the_force_field_built_before():
+    """``force_fields/lj_rf.py`` on the committed configuration gives the
+    ``ForceField`` that the harness built from these keys before it read
+    the kind from a file: equal, so the cells' programs keep their
+    compile-cache keys."""
+    from repro.core.md.system import ForceField
+    cfg = spec.resolve_cell(spec.load_benchmark(),
+                            "lammps-lj-32k.nve.dd4")["config"]
+    assert cfg["force_field"] == "lj_rf"
+    literal = ForceField(eps=tuple(map(tuple, cfg["eps"])),
+                         sigma=tuple(map(tuple, cfg["sigma"])),
+                         r_cut=float(cfg["r_cut"]),
+                         eps_rf=float(cfg["eps_rf"]))
+    built = spec.force_field(cfg)
+    assert built == literal and hash(built) == hash(literal)
+    assert built == ForceField(eps=((1.0,),), sigma=((1.0,),), r_cut=2.5,
+                               eps_rf=float("inf"))
+
+
 def test_metric_listed_for_other_cells_is_left_out():
     bench = json.loads((FIX / "BENCHMARK.json").read_text())
     cell = spec.resolve_cell(bench, "fixture-cfg.other", root=FIX,
@@ -53,6 +88,8 @@ def test_every_committed_cell_resolves(cell):
     assert cfg["limits"]["atoms_missing"] == 0
     assert cfg["limits"]["atoms_dropped"] == 0
     assert callable(spec.reference(cfg).verlet)
+    assert (BENCH / "force_fields" / f"{cfg['force_field']}.py").is_file()
+    assert spec.force_field(cfg).r_cut == cfg["r_cut"]
     for m in resolved["per_layer"]:
         assert callable(spec.metric_reader(m["name"]))
     assert (ROOT / "bench" / "traffic"

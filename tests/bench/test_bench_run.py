@@ -153,12 +153,37 @@ def test_too_few_chips_is_refused(monkeypatch):
         harness.require_accelerator(4)
 
 
-def test_traced_run_reports_per_layer_metrics():
+BOUNDARY = ("rebin_ms_per_block", "rebin_force_ms_per_block",
+            "prune_ms_per_block", "engine_idle_ms_per_block",
+            "schedule_idle_ms_per_block")
+CELLS = [w["name"] for w in harness.spec.load_benchmark()["workloads"]]
+
+
+@pytest.mark.parametrize("workload", CELLS)
+def test_traced_run_reports_per_layer_metrics(workload, monkeypatch):
     """With the profiler on, the result carries the cell's per-layer
     metrics that find something to read (the CPU trace has no TPU
     device, so the device-trace metrics are left out, never zero),
-    ``busy_s``/``window_s`` and a breakdown; ``correct`` means the same."""
-    out = run_small(small_cell(), trace=True)
+    ``busy_s``/``window_s`` and a breakdown; ``correct`` means the same.
+    Every cell lists the block-boundary metrics, and the run parses its
+    trace once: the readers take the host spans from the reduced trace."""
+    import gzip
+    opened = []
+    gzip_open = gzip.open
+
+    def counting_open(path, mode="rb", *a, **kw):
+        if str(path).endswith(".trace.json.gz") and "r" in mode:
+            opened.append(path)
+        return gzip_open(path, mode, *a, **kw)
+    monkeypatch.setattr(gzip, "open", counting_open)
+    cell = small_cell(workload)
+    listed = {m["name"] for m in cell["per_layer"]}
+    assert set(BOUNDARY) <= listed
+    out = run_small(cell, trace=True)
+    assert len(opened) == 1
+    assert set(out["metrics"]) <= listed
+    assert {m["name"] for m in cell["per_layer"]
+            if m["source"] == "program_counter"} <= set(out["metrics"])
     assert out["correct"] is True, out["checks"]
     assert list(out)[-1] == "checks"
     assert out["metrics"]["compiles_in_window"]["value"] == 0

@@ -54,7 +54,7 @@ def test_phase_is_the_innermost_obs_scope(text, phase):
     assert tr.phase_of(text) == phase
 
 
-def synthetic():
+def synthetic(spans=()):
     """Window [0.1, 1.1) us on two devices (times below in ns).
 
     Device 0: a ``while`` op [100, 500) around a halo op [100, 200), a
@@ -62,7 +62,7 @@ def synthetic():
     rebin [520, 680); a second block's force op [700, 1000); an async
     halo collective in flight [1000, 1050) with nothing beside it.
     Block programs [100, 500) and [700, 1050).  Device 1: one force op
-    [100, 300).
+    [100, 300).  ``spans``: further host spans as (name, start_us, dur_us).
     """
     us = 1e-3
     ops = [
@@ -84,7 +84,8 @@ def synthetic():
                  (0, "jit_block_sched(1)", 700 * us, 350 * us)],
         host=[("bench.window", 100 * us, 1000 * us),
               ("bench.simulate", 100 * us, 480 * us),
-              ("bench.simulate", 580 * us, 520 * us), ("other", 0, 5)]))
+              ("bench.simulate", 580 * us, 520 * us), ("other", 0, 5),
+              *spans]))
 
 
 def test_window_devices_and_busy():
@@ -124,6 +125,37 @@ def test_breakdown_ops_and_labelled_idle_gaps():
     assert sorted(v for _n, v in b["idle_gaps"]) == \
         pytest.approx([20e-9, 20e-9, 50e-9])
     assert set(gaps) == {"bench.simulate"}
+
+
+def test_idle_gaps_take_the_innermost_obs_span_over_them():
+    """The program's ``obs.*`` host spans, nested under the harness's
+    ``bench.simulate``, are kept apart from the ``bench.*`` annotations
+    and name each of the longest idle gaps: the innermost span over most
+    of it.  The device readings are those of the trace without them."""
+    us = 1e-3
+    plain = synthetic()
+    r = synthetic([
+        ("obs.simulate", 100 * us, 480 * us),
+        ("obs.rebin_dispatch", 495 * us, 30 * us),     # all of [500, 520)
+        ("obs.simulate", 580 * us, 520 * us),
+        ("obs.schedule_read", 670 * us, 40 * us),      # all of [680, 700)
+        ("obs.metrics_read", 1040 * us, 20 * us),      # 10 of [1000, 1050)
+    ])
+    assert {n for *_x, n in r.host} == {"bench.window", "bench.simulate"}
+    assert sorted(n for *_x, n in r.spans) == [
+        "obs.metrics_read", "obs.rebin_dispatch", "obs.schedule_read",
+        "obs.simulate", "obs.simulate"]
+    assert (r.t0, r.t1, r.blocks) == (plain.t0, plain.t1, plain.blocks)
+    assert [r.busy_ns(d) for d in r.devices] == \
+        [plain.busy_ns(d) for d in plain.devices]
+    b = r.breakdown()
+    assert b["device_ops"] == plain.breakdown()["device_ops"]
+    assert [n for n, _v in b["idle_gaps"]] == [
+        "obs.simulate", "obs.rebin_dispatch", "obs.schedule_read"]
+    assert [v for _n, v in b["idle_gaps"]] == \
+        pytest.approx([50e-9, 20e-9, 20e-9])
+    # only the longest gaps are named
+    assert r.breakdown(top=1)["idle_gaps"] == [["obs.simulate", 50e-9]]
 
 
 def test_window_is_cut_to_what_every_device_trace_holds():
